@@ -53,5 +53,11 @@ def run(quick: bool = False):
     emit("fig8/rule_selection_overhead", dt * 1e6, f"{dt*1e9:.0f}ns/call")
 
 
-if __name__ == "__main__":
+def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()
+
+
+if __name__ == "__main__":
+    main()

@@ -135,5 +135,11 @@ def run(quick: bool = False):
          f"geot={geomean(sp_all['geot']):.2f}x")
 
 
-if __name__ == "__main__":
+def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()
+
+
+if __name__ == "__main__":
+    main()

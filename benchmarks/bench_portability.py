@@ -53,5 +53,11 @@ def run(quick: bool = False):
              f"{geomean(ratios):.3f}")
 
 
-if __name__ == "__main__":
+def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()
+
+
+if __name__ == "__main__":
+    main()

@@ -545,6 +545,8 @@ def main():
     ap.add_argument("--json", default=None,
                     help="write emitted rows to this JSON artifact")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.smoke:
         run_smoke()

@@ -64,5 +64,11 @@ def run(quick: bool = False):
     emit("fig7/geomean_speedup_vs_bcoo", 0.0, f"{geomean(speedups):.2f}x")
 
 
-if __name__ == "__main__":
+def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()
+
+
+if __name__ == "__main__":
+    main()

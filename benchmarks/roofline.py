@@ -164,5 +164,11 @@ def run(quick: bool = False):
     return rows
 
 
-if __name__ == "__main__":
+def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()
+
+
+if __name__ == "__main__":
+    main()

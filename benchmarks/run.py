@@ -19,6 +19,8 @@ import sys
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     quick = os.environ.get("REPRO_BENCH_QUICK", "0") not in ("0", "")
     from benchmarks import (bench_decision_tree, bench_end2end,
                             bench_portability, bench_segment_reduce,

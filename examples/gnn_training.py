@@ -15,6 +15,10 @@ The example *asserts the training contract itself*:
     uninterrupted run — providers are deterministic in the step index
     and the PRNG key rides the checkpointed TrainState.
 
+The uninterrupted and the killed run are both child processes, started
+before this process touches a device: on a TPU the chip belongs to one
+process at a time.
+
 Usage:
   python examples/gnn_training.py                  # full smoke (CI default)
   python examples/gnn_training.py --models gcn --steps 60
@@ -23,6 +27,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import subprocess
 import sys
@@ -69,26 +74,31 @@ def train_full(model: str, args):
 
 
 def kill_and_resume(args):
-    """Child process trains gcn and dies mid-run; we resume from its
-    checkpoint and require the combined trajectory to match the
-    uninterrupted run's to <= 1e-6."""
-    full = train_full("gcn", args)
+    """Two child runs of gcn — one uninterrupted (its losses to a file),
+    one killed mid-run with checkpoints — then the resume in this process,
+    whose trajectory must match the uninterrupted one to <= 1e-6."""
     kill_at = args.steps // 2 - 1
     with tempfile.TemporaryDirectory(prefix="repro_train_ckpt_") as d:
         cmd = [sys.executable, os.path.abspath(__file__),
                "--models", "gcn", "--steps", str(args.steps),
                "--lr", str(args.lr), "--seed", str(args.seed),
-               "--hidden", str(args.hidden), "--impl", args.impl,
-               "--ckpt-dir", d, "--ckpt-every", str(args.ckpt_every),
-               "--kill-at", str(kill_at)]
-        proc = subprocess.run(cmd, capture_output=True, text=True)
-        assert proc.returncode == 0, proc.stderr
-        task, data, cfg = build("gcn", args, ckpt_dir=d)
+               "--hidden", str(args.hidden), "--impl", args.impl]
+        losses_path = os.path.join(d, "losses.json")
+        ckpt_dir = os.path.join(d, "ckpt")
+        for leg in (["--losses-out", losses_path],
+                    ["--ckpt-dir", ckpt_dir, "--ckpt-every",
+                     str(args.ckpt_every), "--kill-at", str(kill_at)]):
+            proc = subprocess.run(cmd + leg, capture_output=True, text=True)
+            assert proc.returncode == 0, proc.stderr
+            print(proc.stdout, end="")
+        with open(losses_path) as f:
+            full_losses = json.load(f)
+        task, data, cfg = build("gcn", args, ckpt_dir=ckpt_dir)
         res = Trainer(task, data, cfg).fit(resume=True)
         expect_start = (kill_at // args.ckpt_every) * args.ckpt_every
         assert res.start_step == expect_start > 0, (
             res.start_step, expect_start)
-        tail = full.losses[res.start_step:]
+        tail = full_losses[res.start_step:]
         assert len(tail) == len(res.losses)
         worst = max(abs(a - b) for a, b in zip(tail, res.losses))
         assert worst <= 1e-6, (
@@ -146,8 +156,16 @@ def main(argv=None):
     ap.add_argument("--resume", action="store_true",
                     help="resume the first of --models from --ckpt-dir")
     ap.add_argument("--skip-kill-test", action="store_true")
+    ap.add_argument("--losses-out", default=None,
+                    help="(internal) train the first of --models and "
+                         "write its per-step losses to this JSON file")
     args = ap.parse_args(argv)
 
+    if args.losses_out:
+        res = train_full(args.models.split(",")[0], args)
+        with open(args.losses_out, "w") as f:
+            json.dump(res.losses, f)
+        return
     if args.kill_at is not None:
         run_killed(args.models.split(",")[0], args)
         return
@@ -158,11 +176,11 @@ def main(argv=None):
         return
 
     models = [m for m in args.models.split(",") if m]
+    if not args.skip_kill_test and "gcn" in models:
+        kill_and_resume(args)       # first: its children need the device
     for model in models:
         if model != "gcn" or args.skip_kill_test:
             train_full(model, args)
-    if not args.skip_kill_test and "gcn" in models:
-        kill_and_resume(args)
     print("all training checks passed")
 
 

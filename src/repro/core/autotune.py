@@ -490,12 +490,10 @@ def tune(op: str = "segment_reduce", *, idx_size: int, num_segments: int,
         raise ValueError(f"unknown op {op!r}; tunable: {sorted(_OPS)}")
     backend = jax.default_backend()
     if interpret is None and measure_fn is None:
-        # same resolution as the real op calls (REPRO_PALLAS_INTERPRET
-        # included) — the sweep must measure the mode that will run
+        # same resolution as the real op calls — the sweep must measure the
+        # mode that will run
         from repro.kernels.ops import _default_interpret
         interpret = _default_interpret()
-    if interpret and backend != "cpu":
-        backend += "+interp"        # never serve interpret sweeps to Mosaic
     from repro.core.config_space import io_dtype_bytes
     feats = InputFeatures(int(idx_size), int(num_segments), int(feat),
                           dtype_bytes=io_dtype_bytes(io_dtype))

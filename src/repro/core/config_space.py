@@ -10,8 +10,8 @@ Parameter mapping (GPU → TPU, see DESIGN.md §2):
 
 Like the paper (§III-C) we prune the space to a constant-size candidate set
 grounded in hardware constraints: N_b multiples of the 128-lane register
-width, M_b multiples of the 8-sublane height, and VMEM budget
-(in + out + one-hot tiles ≤ ~16 MiB/2 for double buffering).
+width, M_b multiples of the 8-sublane height, and the VMEM budget
+(double-buffered in + out + one-hot tiles ≤ VMEM_BYTES).
 """
 from __future__ import annotations
 
@@ -19,7 +19,10 @@ import dataclasses
 import itertools
 from typing import Iterator, List
 
-VMEM_BYTES = 16 * 1024 * 1024          # v5e VMEM per core
+# the scoped VMEM limit every kernel is compiled with
+# (kernels.layout.compiler_params); the largest config of the space
+# compiles under it for a v5e (tests/test_mosaic_compile.py)
+VMEM_BYTES = 16 * 1024 * 1024
 LANES = 128                            # vector register lanes
 SUBLANES = 8                           # vector register sublanes (fp32)
 

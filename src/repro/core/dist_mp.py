@@ -43,7 +43,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PS
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import ops as geot
 from repro.core.config_space import KernelConfig
@@ -157,7 +157,7 @@ def mp_sharded(x, pg, *, reduce: str = "sum", edge_weight=None, pplan=None,
     else:
         fn, args, in_specs = local, args + [w_stack], in_specs + [PS(axis_name)]
     return shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=PS(), check_rep=False)(*args)
+                     out_specs=PS(), check_vma=False)(*args)
 
 
 def mp_transform_sharded(x, w, pg, *, reduce: str = "sum", edge_weight=None,
@@ -250,7 +250,7 @@ def segment_softmax_sharded(e, pg, *, pplan=None, mesh: Optional[Mesh] = None,
         return p2[:, 0] if squeeze else p2
 
     out = shard_map(local, mesh=mesh, in_specs=(PS(axis_name),) * 5,
-                    out_specs=PS(axis_name), check_rep=False)(
+                    out_specs=PS(axis_name), check_vma=False)(
         e_stack, pg.dst_global, pg.edge_valid, pplan.chunk_first,
         pplan.chunk_count)
     # out_specs concatenate the per-shard blocks; restack to (S, E_pad, ...)

@@ -97,6 +97,17 @@ class PartitionedGraph:
     def tree_unflatten(cls, aux, children):
         return cls(*children, *aux)
 
+    def on_mesh(self, mesh) -> "PartitionedGraph":
+        """This partition placed as the ``shard_map`` specs of
+        :mod:`repro.core.dist_mp` read it: stacked leaves one shard per
+        device of the 1-D ``mesh``, ``deg`` replicated."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        stacked = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
+        leaves, aux = self.tree_flatten()
+        placed = [jax.device_put(a, stacked) for a in leaves[:-1]]
+        deg = jax.device_put(leaves[-1], NamedSharding(mesh, PartitionSpec()))
+        return self.tree_unflatten(aux, placed + [deg])
+
     # -- shard/unshard helpers ----------------------------------------------
     def shard_nodes(self, x):
         """(V, ...) global node values -> (S, V_pad, ...) stacked local

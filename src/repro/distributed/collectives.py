@@ -24,7 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PS
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.optim import compression
 
@@ -123,7 +123,7 @@ def make_ring_matmul(mesh: Mesh, axis: str = "model"):
         shard_map, mesh=mesh,
         in_specs=(PS(None, axis), PS(axis, None)),
         out_specs=PS(None, None),
-        check_rep=False)   # replication via ppermute isn't statically inferable
+        check_vma=False)   # replication via ppermute isn't statically inferable
     def fn(x_local, w_local):
         return ring_psum_matmul(x_local, w_local, axis)
     return fn

@@ -15,7 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PS
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def pipeline_forward(stage_fn, stage_params, x_micro, *, mesh: Mesh,
@@ -69,5 +69,5 @@ def pipeline_forward(stage_fn, stage_params, x_micro, *, mesh: Mesh,
         body, mesh=mesh,
         in_specs=(PS(axis), PS()),       # params sharded by stage, x replicated
         out_specs=PS(),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, x_micro)

@@ -45,26 +45,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.config_space import VMEM_BYTES, KernelConfig, io_dtype_bytes
 from repro.kernels.gather_segment_reduce import _gather_chunk
-from repro.kernels.segment_reduce import _resolve_plan, _round_up, chunk_metadata
+from repro.kernels.layout import (LANES, chunk_stream, compiler_params,
+                                  mxu_dot, onehot_t, stream_spec, to_words,
+                                  unpack_words, weighted_onehot, word_cols)
+from repro.kernels.segment_reduce import (_resolve_plan, _round_up,
+                                          chunk_metadata)
 
 
 def fusable(d_in: int, d_out: int, dtype, config: KernelConfig,
             budget: int = VMEM_BYTES) -> bool:
-    """Does one launch's VMEM working set fit? (W tile + fp32 accumulator +
-    staging chunk + out block, double-buffer headroom on the staged chunk.)"""
+    """Does one launch's VMEM working set fit? The W tile and the out block
+    are pipelined operands (two buffers each); the fp32 accumulator and the
+    staged chunk of words are single scratch buffers."""
     b = io_dtype_bytes(dtype)
-    d_in_pad = _round_up(max(d_in, 1), 128)
+    d_in_pad = word_cols(d_in, dtype)
     d_out_pad = _round_up(max(d_out, 1), 128)
-    w_tile = d_in_pad * d_out_pad * b
+    w_tile = 2 * d_in_pad * d_out_pad * b
     acc = config.s_b * d_in_pad * 4
-    stage = 2 * config.m_b * d_in_pad * b
-    out = config.s_b * d_out_pad * b
+    stage = config.m_b * d_in_pad * b
+    out = 2 * config.s_b * d_out_pad * b
     return w_tile + acc + stage + out <= budget
 
 
 def _body(cf_ref, cc_ref, gidx_ref, idx_ref, wt_ref, h_ref, wm_ref, o_ref,
           xbuf_ref, acc_ref, sem, *scratch, s_b: int, has_weight: bool,
-          reduce: str):
+          reduce: str, io_dtype):
     b, k = pl.program_id(0), pl.program_id(1)
     cnt_ref = scratch[0] if reduce == "mean" else None
 
@@ -76,25 +81,20 @@ def _body(cf_ref, cc_ref, gidx_ref, idx_ref, wt_ref, h_ref, wm_ref, o_ref,
 
     @pl.when(k < cc_ref[b])
     def _accumulate():
-        _gather_chunk(gidx_ref, h_ref, xbuf_ref, sem, 0, xbuf_ref.shape[1])
-        xg = xbuf_ref[...]
-        if has_weight:
-            xg = xg * wt_ref[0, :][:, None].astype(xg.dtype)
-        seg = idx_ref[0, :]
-        m_b = seg.shape[0]
-        rel = seg - b * s_b
-        cols = jax.lax.broadcasted_iota(jnp.int32, (m_b, s_b), 1)
-        onehot = (rel[:, None] == cols).astype(xg.dtype)
-        acc_ref[...] += jax.lax.dot_general(
-            onehot, xg, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(acc_ref.dtype)
+        hit = onehot_t(idx_ref, b, s_b)
+        a = weighted_onehot(hit, wt_ref if has_weight else None, io_dtype)
+        cols = acc_ref.shape[1] // xbuf_ref.shape[0]
+        for t in range(xbuf_ref.shape[0]):          # whole rows, tile by tile
+            _gather_chunk(gidx_ref, h_ref, xbuf_ref.at[t], sem, t)
+            xg = unpack_words(xbuf_ref[t], io_dtype).astype(io_dtype)
+            acc_ref[:, t * cols:(t + 1) * cols] += mxu_dot(a, xg)
         if reduce == "mean":
-            # one-hot column sums == per-segment row counts (padding rows
+            # one-hot row sums == per-segment row counts (padding rows
             # carry seg == num_segments and only ever land in the guard
             # rows the caller slices away — same convention as the gather
             # kernel's fused mean)
-            cnt_ref[...] += jnp.sum(onehot.astype(jnp.float32),
-                                    axis=0)[:, None]
+            cnt_ref[...] += jnp.sum(hit.astype(jnp.float32), axis=1,
+                                    keepdims=True)
 
     # in-kernel GEMM once per output block, after its last owned chunk
     # (blocks owning no chunks fire at k == 0 with a zero accumulator)
@@ -103,10 +103,8 @@ def _body(cf_ref, cc_ref, gidx_ref, idx_ref, wt_ref, h_ref, wm_ref, o_ref,
         agg = acc_ref[...]
         if reduce == "mean":
             agg = agg / jnp.maximum(cnt_ref[...], 1.0)
-        o_ref[...] = jax.lax.dot_general(
-            agg.astype(wm_ref.dtype), wm_ref[...],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        o_ref[...] = mxu_dot(agg.astype(wm_ref.dtype),
+                             wm_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -122,21 +120,24 @@ def _fused_transform_reduce_impl(h, wm, gather_idx, seg_idx, weight,
     v, d_in = h.shape
     d_out = wm.shape[1]
     s_b, m_b = config.s_b, config.m_b
-    d_in_pad = _round_up(max(d_in, 1), 128)
+    d_in_pad = word_cols(d_in, h.dtype)
     d_out_pad = _round_up(max(d_out, 1), 128)
     m_pad = _round_up(max(m, 1), m_b)
     s_pad = _round_up(num_segments, s_b)
 
-    hp = jnp.pad(h, ((0, 1), (0, d_in_pad - d_in)))  # +1 guard row
+    # H travels as (tiles, V+1, 128) words with a +1 guard row (see the
+    # gather kernel)
+    hw = to_words(jnp.pad(h, ((0, 1), (0, 0))), tiles_first=True)
     wmp = jnp.pad(wm, ((0, d_in_pad - d_in), (0, d_out_pad - d_out)))
     gidxp = jnp.pad(gather_idx.astype(jnp.int32), (0, m_pad - m),
                     constant_values=v)               # padding gathers guard row
     idxp = jnp.pad(seg_idx.astype(jnp.int32), (0, m_pad - m),
                    constant_values=num_segments)
-    wtp = jnp.pad(weight, (0, m_pad - m))            # io dtype, like the gather
-    gidx2d = gidxp.reshape(m_pad // m_b, m_b)
-    idx2d = idxp.reshape(m_pad // m_b, m_b)
-    wt2d = wtp.reshape(m_pad // m_b, m_b)
+    # streams as in the gather kernel's PR schedule: gather rows are SMEM
+    # scalars, segment ids and io-dtype weights VMEM vectors
+    gidx3 = gidxp.reshape(m_pad // m_b, 1, m_b)
+    idx3 = idxp.reshape(m_pad // m_b, 1, m_b)
+    wt3 = chunk_stream(weight, m_pad, m_b, fill=0)
 
     if plan is not None:
         chunk_first, chunk_count = plan.chunk_first, plan.chunk_count
@@ -148,21 +149,21 @@ def _fused_transform_reduce_impl(h, wm, gather_idx, seg_idx, weight,
         max_chunks = m_pad // m_b
 
     def row_map(b, k, cf, cc):
-        return (cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0)), 0)
+        return cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(out_blocks, max_chunks),
         in_specs=[
-            pl.BlockSpec((1, m_b), row_map),                   # gather_idx
-            pl.BlockSpec((1, m_b), row_map),                   # seg_idx
-            pl.BlockSpec((1, m_b), row_map),                   # edge weight
-            pl.BlockSpec(memory_space=pltpu.ANY),              # H (unblocked)
+            stream_spec(m_b, row_map, smem=True),              # gather_idx
+            stream_spec(m_b, row_map, smem=False),             # seg_idx
+            stream_spec(m_b, row_map, smem=False),             # edge weight
+            pl.BlockSpec(memory_space=pl.ANY),                 # H (unblocked)
             pl.BlockSpec((d_in_pad, d_out_pad), lambda b, k, cf, cc: (0, 0)),
         ],
         out_specs=pl.BlockSpec((s_b, d_out_pad), lambda b, k, cf, cc: (b, 0)),
         scratch_shapes=(
-            [pltpu.VMEM((m_b, d_in_pad), h.dtype),             # staged rows
+            [pltpu.VMEM((hw.shape[0], m_b, LANES), hw.dtype),  # staged rows
              pltpu.VMEM((s_b, d_in_pad), jnp.float32),         # fp32 segment acc
              pltpu.SemaphoreType.DMA]
             + ([pltpu.VMEM((s_b, 1), jnp.float32)]             # mean counts
@@ -170,11 +171,13 @@ def _fused_transform_reduce_impl(h, wm, gather_idx, seg_idx, weight,
     )
     out = pl.pallas_call(
         functools.partial(_body, s_b=s_b, has_weight=has_weight,
-                          reduce=reduce),
+                          reduce=reduce, io_dtype=h.dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_pad, d_out_pad), h.dtype),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(chunk_first, chunk_count, gidx2d, idx2d, wt2d, hp, wmp)
+        name="fused_transform_reduce",
+    )(chunk_first, chunk_count, gidx3, idx3, wt3, hw, wmp)
     return out[:num_segments, :d_out]
 
 

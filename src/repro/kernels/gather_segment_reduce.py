@@ -13,7 +13,7 @@ reduction type does not change the schedule):
 
   * ``sum``  — the paper's SpMM (weighted) / message-sum (unweighted);
   * ``mean`` — per-segment counts are accumulated inside the same kernel
-    (a (S_b, 1) VMEM scratch fed by the one-hot column sums on PR, by a
+    (a (S_b, 1) VMEM scratch fed by the one-hot row sums on PR, by a
     per-open-segment counter on SR) and the output block is divided by
     them at its final chunk — no second count launch;
   * ``max``  — SR running-maximum walk with a -inf identity (matching
@@ -23,9 +23,10 @@ reduction type does not change the schedule):
 Weighted variants reduce over ``w[i]·H[gidx[i]]`` (mean divides by the row
 count, matching the reference oracle's "mean of the weighted messages").
 
-Roofline note: per-row DMA granularity is N_b·dtype bytes; below 512 B the
-gather runs below peak HBM bandwidth (modelled in
-``repro.core.costmodel.spmm_cost`` and visible in §Roofline).
+Roofline note: each DMA moves one 128-word tile of one row (512 B; the
+layout Mosaic accepts for a one-row copy, see :mod:`repro.kernels.layout`),
+so a row of F values costs ceil(F·dtype / 512 B) copies, and rows narrower
+than a tile still move a whole one.
 """
 from __future__ import annotations
 
@@ -38,49 +39,42 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.config_space import KernelConfig
-from repro.kernels.segment_reduce import _resolve_plan, _round_up, chunk_metadata
+from repro.kernels.layout import (LANES, chunk_stream, compiler_params,
+                                  mxu_dot, onehot_t, stream_spec, to_words,
+                                  unpack_words, weighted_onehot, word_ratio)
+from repro.kernels.segment_reduce import (_resolve_plan, _round_up,
+                                          chunk_metadata)
 
 
-def _gather_chunk(gidx_ref, h_ref, xbuf_ref, sem, j: jax.Array, n_b: int):
-    """DMA-gather the chunk's H rows (column tile j) into VMEM staging.
+def _gather_chunk(gidx_ref, h_ref, xbuf_ref, sem, j):
+    """DMA-gather the chunk's rows of word tile j of H into VMEM staging.
 
-    Software-pipelined: row i+1's copy is issued before waiting on row i,
-    so each DMA's latency hides behind the next one's issue (the per-row
-    granularity penalty below 512 B remains — modelled in
-    costmodel.spmm_cost and visible in §Roofline)."""
+    ``h_ref`` is H as (tiles, V+1, 128) words (layout.to_words), so one row
+    of one tile is a contiguous 512-byte copy. Software-pipelined: row
+    i+1's copy is issued before waiting on row i, so each DMA's latency
+    hides behind the next one's issue."""
     m_b = gidx_ref.shape[1]
 
-    def start(i):
-        g = gidx_ref[0, i]
-        cp = pltpu.make_async_copy(
-            h_ref.at[pl.ds(g, 1), pl.ds(j * n_b, n_b)],
-            xbuf_ref.at[pl.ds(i, 1), :],
-            sem,
-        )
-        cp.start()
-        return cp
+    def copy(i):
+        return pltpu.make_async_copy(h_ref.at[j, pl.ds(gidx_ref[0, i], 1), :],
+                                     xbuf_ref.at[pl.ds(i, 1), :], sem)
 
-    first = start(0)
+    copy(0).start()
 
-    def copy_row(i, prev_started):
+    def copy_row(i, carry):
         # issue row i+1 while row i is in flight, then retire row i
         @pl.when(i + 1 < m_b)
         def _():
-            start(i + 1)
-        g = gidx_ref[0, i]
-        pltpu.make_async_copy(
-            h_ref.at[pl.ds(g, 1), pl.ds(j * n_b, n_b)],
-            xbuf_ref.at[pl.ds(i, 1), :],
-            sem,
-        ).wait()
-        return prev_started
+            copy(i + 1).start()
+        copy(i).wait()
+        return carry
 
     jax.lax.fori_loop(0, m_b, copy_row, 0, unroll=False)
 
 
 def _pr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
-             xbuf_ref, sem, *scratch, s_b: int, n_b: int, has_weight: bool,
-             reduce: str):
+             xbuf_ref, sem, *scratch, s_b: int, has_weight: bool,
+             reduce: str, io_dtype):
     b, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     cnt_ref = scratch[0] if reduce == "mean" else None
 
@@ -92,25 +86,21 @@ def _pr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
 
     @pl.when(k < cc_ref[b])
     def _compute():
-        _gather_chunk(gidx_ref, h_ref, xbuf_ref, sem, j, n_b)
-        xg = xbuf_ref[...]
-        if has_weight:
-            xg = xg * w_ref[0, :][:, None].astype(xg.dtype)
-        seg = idx_ref[0, :]
-        m_b = seg.shape[0]
-        rel = seg - b * s_b
-        cols = jax.lax.broadcasted_iota(jnp.int32, (m_b, s_b), 1)
-        onehot = (rel[:, None] == cols).astype(xg.dtype)
-        o_ref[...] += jax.lax.dot_general(
-            onehot, xg, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=o_ref.dtype).astype(o_ref.dtype)
+        _gather_chunk(gidx_ref, h_ref, xbuf_ref, sem, j)
+        # unpacked bf16 values are exact in bf16 again: the MXU runs at the
+        # io width and accumulates fp32
+        xg = unpack_words(xbuf_ref[...], io_dtype).astype(io_dtype)
+        hit = onehot_t(idx_ref, b, s_b)
+        a = weighted_onehot(hit, w_ref if has_weight else None, io_dtype)
+        o_ref[...] += mxu_dot(a, xg)
         if reduce == "mean":
-            # column sums of the one-hot == per-segment row counts. Padding
+            # row sums of the one-hot == per-segment row counts. Padding
             # rows carry seg == num_segments: when num_segments % s_b != 0
             # they DO land in the last block's window and count into (and
             # divide) the guard row — correct only because the caller
             # slices the output to [:num_segments].
-            cnt_ref[...] += jnp.sum(onehot.astype(jnp.float32), axis=0)[:, None]
+            cnt_ref[...] += jnp.sum(hit.astype(jnp.float32), axis=1,
+                                    keepdims=True)
 
     if reduce == "mean":
         # normalize once, after the block's last owned chunk accumulated
@@ -120,8 +110,8 @@ def _pr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
 
 
 def _sr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
-             xbuf_ref, sem, acc_ref, st_ref, *scratch, s_b: int, n_b: int,
-             has_weight: bool, reduce: str):
+             xbuf_ref, sem, acc_ref, st_ref, *scratch, s_b: int,
+             has_weight: bool, reduce: str, io_dtype):
     b, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     cnt_ref, ca_ref = scratch if reduce == "mean" else (None, None)
     # max identity is -inf, matching jax.ops.segment_max on empty segments
@@ -136,9 +126,8 @@ def _sr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
 
     @pl.when(k < cc_ref[b])
     def _compute():
-        _gather_chunk(gidx_ref, h_ref, xbuf_ref, sem, j, n_b)
-        seg = idx_ref[0, :]
-        m_b = seg.shape[0]
+        _gather_chunk(gidx_ref, h_ref, xbuf_ref, sem, j)
+        m_b = idx_ref.shape[1]
 
         def flush():
             p = st_ref[0]
@@ -151,7 +140,7 @@ def _sr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
                 cnt_ref[pl.ds(p, 1), :] += ca_ref[...]
 
         def walk(i, _):
-            r = seg[i] - b * s_b
+            r = idx_ref[0, i] - b * s_b
             in_win = jnp.logical_and(r >= 0, r < s_b)
             opened = st_ref[0] >= 0
 
@@ -161,9 +150,9 @@ def _sr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
                 flush()
                 st_ref[0] = -1
 
-            xrow = xbuf_ref[pl.ds(i, 1), :].astype(acc_ref.dtype)
+            xrow = unpack_words(xbuf_ref[pl.ds(i, 1), :], io_dtype)
             if has_weight:
-                xrow = xrow * w_ref[0, i].astype(acc_ref.dtype)
+                xrow = xrow * w_ref[0, i]
 
             @pl.when(jnp.logical_and(in_win, st_ref[0] == r))
             def _():
@@ -208,25 +197,28 @@ def _gather_segment_reduce_impl(h, gather_idx, seg_idx, weight,
                                 plan=None):
     m = gather_idx.shape[0]
     v, n = h.shape
-    s_b, n_b, m_b = config.s_b, config.n_b, config.m_b
-    n_b = min(n_b, _round_up(max(n, 1), 128))
+    s_b, m_b = config.s_b, config.m_b
+    kw = word_ratio(h.dtype)
     m_pad = _round_up(max(m, 1), m_b)
-    n_pad = _round_up(max(n, 1), n_b)
     s_pad = _round_up(num_segments, s_b)
 
-    hp = jnp.pad(h, ((0, 1), (0, n_pad - n)))        # +1 guard row for padding
-    gidxp = jnp.pad(gather_idx.astype(jnp.int32), (0, m_pad - m),
-                    constant_values=v)               # padding gathers guard row
+    # H as (tiles, V+1, 128) words, +1 guard row that padding edges gather;
+    # the feature tile is one 128-word tile (128·k values) whatever
+    # config.n_b says — a wider row is not one DMA on Mosaic
+    hw = to_words(jnp.pad(h, ((0, 1), (0, 0))), tiles_first=True)
     idxp = jnp.pad(seg_idx.astype(jnp.int32), (0, m_pad - m),
                    constant_values=num_segments)
-    # weights stay in their io dtype through HBM/VMEM — upcasting happens
-    # inside the accumulator (SR walk) or via the MXU's fp32
-    # preferred_element_type (PR), so weighted bf16 reduces keep the
-    # half-bandwidth win on the weight stream too
-    wp = jnp.pad(weight, (0, m_pad - m))
-    gidx2d = gidxp.reshape(m_pad // m_b, m_b)
-    idx2d = idxp.reshape(m_pad // m_b, m_b)
-    w2d = wp.reshape(m_pad // m_b, m_b)
+    pr = config.schedule == "PR"
+    # per-chunk streams: gather rows (DMA addresses) are always SMEM
+    # scalars; the PR schedule reads segment ids and weights as VMEM
+    # vectors (one-hot build), the SR walk reads them as SMEM scalars —
+    # SMEM holds 32-bit words only, so the SR weight stream rides fp32,
+    # while PR weights stay in the io dtype (upcast by the MXU's fp32
+    # accumulation)
+    gidx3 = chunk_stream(gather_idx.astype(jnp.int32), m_pad, m_b, fill=v)
+    idx3 = idxp.reshape(m_pad // m_b, 1, m_b)
+    w3 = chunk_stream(weight if pr else weight.astype(jnp.float32), m_pad,
+                      m_b, fill=0)
 
     if plan is not None:
         chunk_first, chunk_count = plan.chunk_first, plan.chunk_count
@@ -234,12 +226,12 @@ def _gather_segment_reduce_impl(h, gather_idx, seg_idx, weight,
         chunk_first, chunk_count = chunk_metadata(idxp, num_segments, s_b,
                                                   m_b, m_pad)
     out_blocks = s_pad // s_b
-    n_tiles = n_pad // n_b
+    n_tiles = hw.shape[0]
     if max_chunks is None:
         max_chunks = m_pad // m_b
 
     def row_map(b, j, k, cf, cc):
-        return (cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0)), 0)
+        return cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0))
 
     def o_map(b, j, k, cf, cc):
         return (b, j)
@@ -247,39 +239,42 @@ def _gather_segment_reduce_impl(h, gather_idx, seg_idx, weight,
     common = dict(
         grid=(out_blocks, n_tiles, max_chunks),
         in_specs=[
-            pl.BlockSpec((1, m_b), row_map),                  # gather_idx
-            pl.BlockSpec((1, m_b), row_map),                  # seg_idx
-            pl.BlockSpec((1, m_b), row_map),                  # weight
-            pl.BlockSpec(memory_space=pltpu.ANY),             # H (unblocked)
+            stream_spec(m_b, row_map, smem=True),             # gather_idx
+            stream_spec(m_b, row_map, smem=not pr),           # seg_idx
+            stream_spec(m_b, row_map, smem=not pr),           # weight
+            pl.BlockSpec(memory_space=pl.ANY),                # H (unblocked)
         ],
-        out_specs=pl.BlockSpec((s_b, n_b), o_map),
+        out_specs=pl.BlockSpec((s_b, LANES * kw), o_map),
     )
-    scratch = [pltpu.VMEM((m_b, n_b), h.dtype), pltpu.SemaphoreType.DMA]
+    scratch = [pltpu.VMEM((m_b, LANES), hw.dtype), pltpu.SemaphoreType.DMA]
     # fused mean: per-segment row counts live next to the output block
     cnt_scratch = [pltpu.VMEM((s_b, 1), jnp.float32)] if reduce == "mean" else []
 
-    if config.schedule == "PR":
+    if pr:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, **common,
             scratch_shapes=scratch + cnt_scratch)
-        body = functools.partial(_pr_body, s_b=s_b, n_b=n_b,
-                                 has_weight=has_weight, reduce=reduce)
+        body = functools.partial(_pr_body, s_b=s_b, has_weight=has_weight,
+                                 reduce=reduce, io_dtype=h.dtype)
     else:
-        sr_scratch = [pltpu.VMEM((1, n_b), jnp.float32),
+        sr_scratch = [pltpu.VMEM((1, LANES * kw), jnp.float32),
                       pltpu.SMEM((1,), jnp.int32)]
         if reduce == "mean":
             sr_scratch += cnt_scratch + [pltpu.VMEM((1, 1), jnp.float32)]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, **common, scratch_shapes=scratch + sr_scratch)
-        body = functools.partial(_sr_body, s_b=s_b, n_b=n_b,
-                                 has_weight=has_weight, reduce=reduce)
+        body = functools.partial(_sr_body, s_b=s_b, has_weight=has_weight,
+                                 reduce=reduce, io_dtype=h.dtype)
 
     out = pl.pallas_call(
         body,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_pad, n_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((s_pad, n_tiles * LANES * kw),
+                                        jnp.float32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(chunk_first, chunk_count, gidx2d, idx2d, w2d, hp)
+        name="gather_segment_reduce",
+    )(chunk_first, chunk_count, gidx3, idx3, w3, hw)
 
     return out[:num_segments, :n].astype(h.dtype)
 

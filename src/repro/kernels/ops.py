@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU-only container the kernels execute with ``interpret=True``
-(Pallas interpreter); on TPU hardware set ``REPRO_PALLAS_INTERPRET=0`` (or
-pass ``interpret=False``) to compile via Mosaic.
+On a TPU the kernels compile through Mosaic. On the CPU backend (the test
+suite, ``JAX_PLATFORMS=cpu``) they run in the Pallas interpreter. Any other
+backend is refused rather than interpreted in silence.
 
 Every wrapper accepts the same ``(plan=, config=, tune=)`` trio with one
 precedence (paper §III-C + the measured tier of :mod:`repro.core.autotune`;
@@ -23,7 +23,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import contextvars
-import os
 import threading
 from typing import Optional
 
@@ -36,10 +35,13 @@ from repro.kernels.segment_reduce import segment_reduce_pallas
 
 
 def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    """Compiled on a TPU, interpreted on the CPU; no other backend runs
+    the kernels."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"the Pallas kernels run on a TPU (or, "
+                           f"interpreted, on the CPU), not on {backend!r}")
+    return backend == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -283,7 +285,7 @@ def sddmm(a, b, row_idx, col_idx, config: Optional[KernelConfig] = None,
         config = select_config(int(row_idx.shape[0]), int(a.shape[0]),
                                int(a.shape[-1]), op="sddmm", tune=tune)
     return sddmm_pallas(a, b, row_idx, col_idx, m_b=config.m_b,
-                        n_b=config.n_b, interpret=interpret)
+                        interpret=interpret)
 
 
 def segment_softmax(x, idx, num_segments: int,

@@ -151,5 +151,6 @@ def segment_matmul_pallas(x, group_sizes, w, m_b: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), x.dtype),
         interpret=interpret,
+        name="segment_matmul",
     )(offsets, fg, gc, xp, wp)
     return out[:m, :n]

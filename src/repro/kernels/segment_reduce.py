@@ -40,6 +40,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.config_space import KernelConfig
+from repro.kernels.layout import (LANES, compiler_params, mxu_dot, onehot_t,
+                                  stream_spec, to_words, unpack_words,
+                                  weighted_onehot, word_cols, word_ratio)
 
 
 # ---------------------------------------------------------------------------
@@ -66,7 +69,7 @@ def chunk_metadata(idx, num_segments: int, s_b: int, m_b: int, m_pad: int):
 # kernel bodies
 # ---------------------------------------------------------------------------
 
-def _pr_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref, *, s_b: int, acc_dtype):
+def _pr_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref, *, s_b: int, io_dtype):
     b, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(k == 0)
@@ -75,20 +78,15 @@ def _pr_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref, *, s_b: int, acc_dtype):
 
     @pl.when(k < cc_ref[b])
     def _compute():
-        seg = idx_ref[0, :]                          # (m_b,) int32
-        rel = seg - b * s_b
-        m_b = seg.shape[0]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (m_b, s_b), 1)
-        onehot = (rel[:, None] == cols).astype(x_ref.dtype)
-        o_ref[...] += jax.lax.dot_general(
-            onehot, x_ref[...],
-            dimension_numbers=(((0,), (0,)), ((), ())),   # contract rows
-            preferred_element_type=acc_dtype,
-        ).astype(o_ref.dtype)
+        # unpacked bf16 values are exact in bf16 again: the MXU runs at the
+        # io width and accumulates fp32
+        x = unpack_words(x_ref[...], io_dtype).astype(io_dtype)
+        a = weighted_onehot(onehot_t(idx_ref, b, s_b), None, io_dtype)
+        o_ref[...] += mxu_dot(a, x)
 
 
 def _sr_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref, acc_ref, st_ref,
-             *, s_b: int, reduce: str):
+             *, s_b: int, reduce: str, io_dtype):
     b, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     # max identity is -inf, matching jax.ops.segment_max on empty segments
     init_val = -jnp.inf if reduce == "max" else 0.0
@@ -100,8 +98,7 @@ def _sr_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref, acc_ref, st_ref,
 
     @pl.when(k < cc_ref[b])
     def _compute():
-        seg = idx_ref[0, :]
-        m_b = seg.shape[0]
+        m_b = idx_ref.shape[1]
 
         def flush():
             p = st_ref[0]
@@ -112,7 +109,7 @@ def _sr_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref, acc_ref, st_ref,
                 o_ref[pl.ds(p, 1), :] = row + acc_ref[...]
 
         def walk(i, _):
-            r = seg[i] - b * s_b
+            r = idx_ref[0, i] - b * s_b
             in_win = jnp.logical_and(r >= 0, r < s_b)
             opened = st_ref[0] >= 0
 
@@ -122,7 +119,7 @@ def _sr_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref, acc_ref, st_ref,
                 flush()
                 st_ref[0] = -1
 
-            xrow = x_ref[pl.ds(i, 1), :].astype(acc_ref.dtype)
+            xrow = unpack_words(x_ref[pl.ds(i, 1), :], io_dtype)
 
             @pl.when(jnp.logical_and(in_win, st_ref[0] == r))
             def _():  # continue open segment
@@ -210,17 +207,24 @@ def segment_reduce_pallas(x, idx, num_segments: int, reduce: str = "sum",
                 / jnp.maximum(cnt, 1.0)[:, None]).astype(x.dtype)
 
     m, n = x.shape
-    s_b, n_b, m_b = config.s_b, config.n_b, config.m_b
-    n_b = min(n_b, _round_up(max(n, 1), 128))
+    s_b, m_b = config.s_b, config.m_b
+    kw = word_ratio(x.dtype)
+    # x travels as 32-bit words (layout.to_words): the SR walk reads single
+    # rows, which Mosaic allows only for 32-bit tiles. A feature tile is
+    # n_b values = n_b / k words, at least one 128-word tile.
+    words = word_cols(n, x.dtype) // kw
+    nbw = min(max(config.n_b // kw // LANES, 1) * LANES, words)
+    w_pad = _round_up(words, nbw)
     m_pad = _round_up(max(m, 1), m_b)
-    n_pad = _round_up(max(n, 1), n_b)
     s_pad = _round_up(num_segments, s_b)
 
-    xp = jnp.pad(x, ((0, m_pad - m), (0, n_pad - n)))
+    xw = to_words(x, tiles_first=False)
+    xw = jnp.pad(xw, ((0, m_pad - m), (0, w_pad - xw.shape[1])))
     # padding rows get segment id = num_segments ⇒ outside every window
     idxp = jnp.pad(idx.astype(jnp.int32), (0, m_pad - m),
                    constant_values=num_segments)
-    idx2d = idxp.reshape(m_pad // m_b, m_b)
+    pr = config.schedule == "PR"
+    idx3 = idxp.reshape(m_pad // m_b, 1, m_b)
 
     if plan is not None:
         chunk_first, chunk_count = plan.chunk_first, plan.chunk_count
@@ -228,17 +232,15 @@ def segment_reduce_pallas(x, idx, num_segments: int, reduce: str = "sum",
         chunk_first, chunk_count = chunk_metadata(idxp, num_segments, s_b,
                                                   m_b, m_pad)
     out_blocks = s_pad // s_b
-    n_tiles = n_pad // n_b
+    n_tiles = w_pad // nbw
     if max_chunks is None:
         max_chunks = m_pad // m_b          # worst case: one block owns all rows
 
-    acc_dtype = jnp.float32
+    def chunk_of(b, j, k, cf, cc):
+        return cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0))
 
     def x_map(b, j, k, cf, cc):
-        return (cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0)), j)
-
-    def idx_map(b, j, k, cf, cc):
-        return (cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0)), 0)
+        return (chunk_of(b, j, k, cf, cc), j)
 
     def o_map(b, j, k, cf, cc):
         return (b, j)
@@ -246,32 +248,32 @@ def segment_reduce_pallas(x, idx, num_segments: int, reduce: str = "sum",
     common = dict(
         grid=(out_blocks, n_tiles, max_chunks),
         in_specs=[
-            pl.BlockSpec((1, m_b), idx_map),
-            pl.BlockSpec((m_b, n_b), x_map),
+            stream_spec(m_b, chunk_of, smem=not pr),  # PR: vector, SR: scalars
+            pl.BlockSpec((m_b, nbw), x_map),
         ],
-        out_specs=pl.BlockSpec((s_b, n_b), o_map),
+        out_specs=pl.BlockSpec((s_b, nbw * kw), o_map),
     )
 
-    if config.schedule == "PR":
+    if pr:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, **common)
-        body = functools.partial(_pr_body, s_b=s_b, acc_dtype=acc_dtype)
+        body = functools.partial(_pr_body, s_b=s_b, io_dtype=x.dtype)
     else:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, **common,
-            scratch_shapes=[pltpu.VMEM((1, n_b), acc_dtype),
+            scratch_shapes=[pltpu.VMEM((1, nbw * kw), jnp.float32),
                             pltpu.SMEM((1,), jnp.int32)])
-        body = functools.partial(_sr_body, s_b=s_b, reduce=reduce)
+        body = functools.partial(_sr_body, s_b=s_b, reduce=reduce,
+                                 io_dtype=x.dtype)
 
     out = pl.pallas_call(
         body,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_pad, n_pad), acc_dtype),
+        out_shape=jax.ShapeDtypeStruct((s_pad, w_pad * kw), jnp.float32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(chunk_first, chunk_count, idx2d, xp)
+        name="segment_reduce",
+    )(chunk_first, chunk_count, idx3, xw)
 
-    out = out[:num_segments, :n]
-    if reduce == "max":
-        # empty segments: match jax.ops.segment_max identity (-inf)
-        return out.astype(x.dtype)
-    return out.astype(x.dtype)
+    # empty max segments keep the -inf identity, as jax.ops.segment_max does
+    return out[:num_segments, :n].astype(x.dtype)

@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.config_space import KernelConfig
+from repro.kernels.layout import compiler_params, stream_spec
 from repro.kernels.segment_reduce import _resolve_plan, _round_up
 
 
@@ -54,7 +55,6 @@ def _softmax_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref,
 
     @pl.when(jnp.logical_and(in_stats, k < cc_ref[b]))
     def _stats():
-        seg = idx_ref[0, :]
 
         def flush():
             p = st_ref[0]
@@ -62,7 +62,7 @@ def _softmax_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref,
             z_ref[pl.ds(p, 1), :] = az_ref[...]
 
         def walk(i, _):
-            r = seg[i] - b * s_b
+            r = idx_ref[0, i] - b * s_b
             in_win = jnp.logical_and(r >= 0, r < s_b)
             opened = st_ref[0] >= 0
 
@@ -98,7 +98,6 @@ def _softmax_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref,
 
     @pl.when(jnp.logical_and(~in_stats, k < cc_ref[b]))
     def _emit():
-        seg = idx_ref[0, :]
         row0 = (cf_ref[b] + k) * m_b
 
         def row_copy(i):
@@ -111,7 +110,7 @@ def _softmax_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref,
             )
 
         def compute_and_start(i, _):
-            r = seg[i] - b * s_b
+            r = idx_ref[0, i] - b * s_b
             in_win = jnp.logical_and(r >= 0, r < s_b)
             rc = jnp.clip(r, 0, s_b - 1)
             xrow = x_ref[pl.ds(i, 1), :].astype(jnp.float32)
@@ -128,7 +127,7 @@ def _softmax_body(cf_ref, cc_ref, idx_ref, x_ref, o_ref,
             return 0
 
         def drain(i, _):
-            r = seg[i] - b * s_b
+            r = idx_ref[0, i] - b * s_b
 
             @pl.when(jnp.logical_and(r >= 0, r < s_b))
             def _():
@@ -155,13 +154,14 @@ def _segment_softmax_impl(x, idx, num_segments: int, config: KernelConfig,
     m_pad = _round_up(max(m, 1), m_b)
     s_pad = _round_up(num_segments, s_b)
 
-    # logits stay in their io dtype through HBM — each row is upcast to the
-    # fp32 online-softmax accumulators only after it lands in VMEM, so bf16
-    # attention logits keep the half-bandwidth read (stats stay fp32)
-    xp = jnp.pad(x, ((0, m_pad - m), (0, h_pad - h)))
+    # the walk reads and the emit writes single rows, which Mosaic allows
+    # only for 32-bit tiles: logits run fp32 through the kernel whatever
+    # their io dtype (heads are padded to a 128-lane tile anyway), and the
+    # result is cast back to the io dtype
+    xp = jnp.pad(x.astype(jnp.float32), ((0, m_pad - m), (0, h_pad - h)))
     idxp = jnp.pad(idx.astype(jnp.int32), (0, m_pad - m),
                    constant_values=num_segments)
-    idx2d = idxp.reshape(m_pad // m_b, m_b)
+    idx3 = idxp.reshape(m_pad // m_b, 1, m_b)
 
     if plan is not None:
         chunk_first, chunk_count = plan.chunk_first, plan.chunk_count
@@ -173,39 +173,38 @@ def _segment_softmax_impl(x, idx, num_segments: int, config: KernelConfig,
     if max_chunks is None:
         max_chunks = m_pad // m_b
 
-    def row_map(b, kk, cf, cc):
+    def chunk_of(b, kk, cf, cc):
         k = jax.lax.rem(kk, max_chunks)
-        return (cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0)), 0)
+        return cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(out_blocks, 2 * max_chunks),
         in_specs=[
-            pl.BlockSpec((1, m_b), row_map),               # seg idx
-            pl.BlockSpec((m_b, h_pad), row_map),           # logits
+            stream_spec(m_b, chunk_of, smem=True),         # seg idx (walk)
+            pl.BlockSpec((m_b, h_pad),                     # logits
+                         lambda *g: (chunk_of(*g), 0)),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),    # per-edge output
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),       # per-edge output
         scratch_shapes=[
             pltpu.VMEM((s_b, h_pad), jnp.float32),         # segment max m
             pltpu.VMEM((s_b, h_pad), jnp.float32),         # segment sum-exp z
             pltpu.VMEM((1, h_pad), jnp.float32),           # open-segment m
             pltpu.VMEM((1, h_pad), jnp.float32),           # open-segment z
             pltpu.SMEM((1,), jnp.int32),                   # open-segment rel
-            pltpu.VMEM((m_b, h_pad), x.dtype),             # output chunk stage
+            pltpu.VMEM((m_b, h_pad), jnp.float32),         # output chunk stage
             pltpu.SemaphoreType.DMA,
         ],
     )
-    # output rides the io dtype too (α ∈ [0, 1] — bf16 holds it to ~2^-8
-    # relative, inside the tiered tolerance): the stage buffer is cast right
-    # before its row DMA, halving the per-edge write *and* the weighted
-    # aggregation's subsequent read for bf16 logits
     out = pl.pallas_call(
         functools.partial(_softmax_body, s_b=s_b, m_b=m_b,
                           max_chunks=max_chunks),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m_pad, h_pad), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((m_pad, h_pad), jnp.float32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(chunk_first, chunk_count, idx2d, xp)
+        name="segment_softmax",
+    )(chunk_first, chunk_count, idx3, xp)
     out = out[:m, :h]
     # rows of dropped segments (idx >= num_segments, the padding convention
     # of pad_graph / partition) belong to no output block, so no phase-1 DMA

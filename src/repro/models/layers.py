@@ -156,7 +156,7 @@ def tp_out_project(x, w_param):
     if ctx is None:
         return x @ w
     mesh, plan = ctx
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as PS
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     m_ax = plan.model_axes[0]
@@ -180,7 +180,7 @@ def tp_out_project(x, w_param):
                    in_specs=(PS(dspec, *([None] * (x.ndim - 2)), m_ax),
                              PS(*w_spec)),
                    out_specs=PS(dspec, *([None] * (x.ndim - 1))),
-                   check_rep=False)
+                   check_vma=False)
     return fn(x, w)
 
 

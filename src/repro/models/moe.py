@@ -168,7 +168,7 @@ def moe_shard_map(prm, x, cfg: ModelConfig):
     sort + fused combine (the paper's ops, applied per shard), and the only
     cross-device traffic is the same (T_local, D) psum a dense TP MLP pays.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as PS
     from repro.distributed.sharding import current_context, spec_for_axes
 
@@ -256,7 +256,7 @@ def moe_shard_map(prm, x, cfg: ModelConfig):
         in_specs=(PS(dspec, None), PS(dspec, None), PS(dspec, None),
                   wu_spec, wg_spec, wd_spec),
         out_specs=PS(dspec, None),
-        check_rep=False)
+        check_vma=False)
     out2d = fn(x2d, top_e, top_p, wu, wg, wd).astype(x.dtype)
 
     if cfg.num_shared_experts:

@@ -63,13 +63,10 @@ def measured_config(bucket: ShapeBucket, feat: int,
 
     from repro.core import autotune
     from repro.core.features import InputFeatures
-    from repro.kernels.ops import _default_interpret
 
     if db is None:
         db = autotune.PerfDB()
     backend = jax.default_backend()
-    if _default_interpret() and backend != "cpu":
-        backend += "+interp"
     feats = InputFeatures(int(bucket.num_edges), int(bucket.num_nodes),
                           int(feat))
     entry = db.get(autotune.perf_key(backend, op, feats))

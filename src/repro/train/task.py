@@ -39,6 +39,8 @@ from typing import Any, NamedTuple, Optional, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.data.graphs import TypedGraph
 from repro.models import gnn
@@ -147,9 +149,9 @@ class NodeClassification:
             raise NotImplementedError("typed layers are single-shard for now")
         static = GraphStatic(self.model, g.num_nodes, g.num_edges, typed,
                              shards)
-        arrays = dict(self._device_arrays(g))
+        arrays = dict(self._device_arrays(g, mesh))
         if shards:
-            part, pplan = self._partitioned(g, shards, config, tune)
+            part, pplan = self._partitioned(g, mesh, config, tune)
             arrays["partition"] = part
             arrays["plan"] = plan if plan is not None else pplan
         else:
@@ -208,8 +210,11 @@ class NodeClassification:
 
     # -- memoized per-graph state -------------------------------------------
 
-    def _device_arrays(self, g) -> dict:
-        hit = self._dev.get(id(g))
+    def _device_arrays(self, g, mesh=None) -> dict:
+        """The graph's arrays on the device — replicated over ``mesh``
+        when given, so no step copies them from the default device."""
+        key = (id(g), None if mesh is None else int(mesh.devices.size))
+        hit = self._dev.get(key)
         if hit is not None and hit[0] is g:
             return hit[1]
         arrays = {"x": jnp.asarray(g.x),
@@ -221,8 +226,10 @@ class NodeClassification:
                           type_perm=jnp.asarray(g.type_perm),
                           inv_type_perm=jnp.asarray(g.inv_type_perm),
                           type_counts=jnp.asarray(g.type_counts))
+        if mesh is not None:
+            arrays = jax.device_put(arrays, NamedSharding(mesh, P()))
         # pin g in the memo: id() is only unique among live objects
-        self._dev[id(g)] = (g, arrays)
+        self._dev[key] = (g, arrays)
         return arrays
 
     def _bucket_plan(self, g, static: GraphStatic, config, tune):
@@ -250,19 +257,24 @@ class NodeClassification:
         return dataclasses.replace(r, max_groups=r.worst_case_groups,
                                    stats=stats)
 
-    def _partitioned(self, g, shards: int, config, tune):
+    def _partitioned(self, g, mesh, config, tune):
+        """The graph's partition and plan, placed on the devices of
+        ``mesh`` as the sharded step reads them."""
+        shards = int(mesh.devices.size)
+        on_shards = NamedSharding(mesh, P(mesh.axis_names[0]))
         pkey = (id(g), shards)
         hit = self._parts.get(pkey)
         if hit is not None and hit[0] is g:
             part = hit[1]
         else:
-            part = g.partition(shards)
+            part = g.partition(shards).on_mesh(mesh)
             self._parts[pkey] = (g, part)
         plkey = (id(g), shards, self.plan_feat, config, tune)
         pplan = self._pplans.get(plkey)
         if pplan is None:
-            pplan = part.make_plan(feat=self.plan_feat, config=config,
-                                   tune=tune)
+            pplan = jax.device_put(
+                part.make_plan(feat=self.plan_feat, config=config,
+                               tune=tune), on_shards)
             self._pplans[plkey] = pplan
         return part, pplan
 

@@ -138,8 +138,15 @@ class Trainer:
         root = jax.random.PRNGKey(self.cfg.seed)
         k_init, k_state = jax.random.split(root)
         params = self.task.init(k_init)
-        return TrainState(params, adamw.init(params, self.cfg.opt),
-                          jnp.zeros((), jnp.int32), k_state)
+        state = TrainState(params, adamw.init(params, self.cfg.opt),
+                           jnp.zeros((), jnp.int32), k_state)
+        if self.mesh is not None:
+            # replicated over the mesh, as the step returns it: a state
+            # left on the default device would retrace the step once
+            from jax.sharding import NamedSharding, PartitionSpec
+            state = jax.device_put(state,
+                                   NamedSharding(self.mesh, PartitionSpec()))
+        return state
 
     @property
     def traces(self) -> int:
@@ -180,7 +187,8 @@ class Trainer:
 
         return jax.jit(step)
 
-    def _executable(self, static) -> Callable:
+    def executable(self, static) -> Callable:
+        """The jitted step for a static signature, built on first use."""
         exe = self._execs.get(static)
         if exe is None:
             exe = self._execs[static] = self._build_step(static)
@@ -223,7 +231,7 @@ class Trainer:
                         tune=self.tune, mesh=self.mesh)
                 root.set(static=repr(static))
                 compiled = static in self._traced_statics
-                exe = self._executable(static)
+                exe = self.executable(static)
                 with span("train.execute" if compiled else "train.compile",
                           static=repr(static)):
                     st, metrics = exe(st, arrays)

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
