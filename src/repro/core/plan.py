@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.config_space import KernelConfig
+from repro.obs import span
 
 __all__ = ["SegmentStats", "SegmentPlan", "PartitionedPlan", "RelationPlan",
            "make_plan", "make_graph_plan", "make_partitioned_plan",
@@ -163,28 +164,37 @@ def make_plan(idx, num_segments: int, feat: int = 128,
         raise ValueError(f"idx must be 1-D, got shape {idx_np.shape}")
     if idx_np.size and np.any(idx_np[1:] < idx_np[:-1]):
         raise ValueError("idx must be sorted non-decreasing")
-    stats = segment_stats(idx_np, num_segments)
-
-    if config is None:
-        from repro.core.heuristics import select_config
-        # data-aware selection: the *live* segment count drives avg degree,
-        # so gapped ids (batched / masked graphs) do not dilute the feature
-        config = select_config(max(int(idx_np.size), 1),
-                               max(stats.live_segments, 1), feat, tune=tune)
-
     m = int(idx_np.size)
-    s_b, m_b = config.s_b, config.m_b
-    m_pad = _round_up(max(m, 1), m_b)
-    idxp = np.full((m_pad,), num_segments, np.int32)
-    idxp[:m] = idx_np
+    with span("plan.build", num_edges=m) as sp:
+        stats = segment_stats(idx_np, num_segments)
 
-    # the kernel's own metadata helper, evaluated concretely on the host —
-    # one formula, so plans can never drift from the per-call path
-    from repro.kernels.segment_reduce import chunk_metadata
-    chunk_first, chunk_count = chunk_metadata(idxp, num_segments, s_b, m_b,
-                                              m_pad)
-    chunk_count_np = np.asarray(chunk_count)
-    max_chunks = max(1, int(chunk_count_np.max())) if chunk_count_np.size else 1
+        if config is None:
+            from repro.core.heuristics import select_config
+            # data-aware selection: the *live* segment count drives avg
+            # degree, so gapped ids (batched / masked graphs) do not dilute
+            # the feature
+            config = select_config(max(m, 1), max(stats.live_segments, 1),
+                                   feat, tune=tune)
+
+        s_b, m_b = config.s_b, config.m_b
+        m_pad = _round_up(max(m, 1), m_b)
+        idxp = np.full((m_pad,), num_segments, np.int32)
+        idxp[:m] = idx_np
+
+        # the kernel's own metadata helper, evaluated concretely on the
+        # host — one formula, so plans can never drift from the per-call
+        # path
+        from repro.kernels.segment_reduce import chunk_metadata
+        chunk_first, chunk_count = chunk_metadata(idxp, num_segments, s_b,
+                                                  m_b, m_pad)
+        chunk_count_np = np.asarray(chunk_count)
+        max_chunks = (max(1, int(chunk_count_np.max()))
+                      if chunk_count_np.size else 1)
+        # the tight grid beside the worst case a bucket-pinned plan walks
+        sp.set(out_blocks=int(chunk_count_np.size),
+               chunks_owned=int(chunk_count_np.sum()), max_chunks=max_chunks,
+               worst_case_chunks=m_pad // m_b,
+               config=f"{config.schedule} s_b={s_b} m_b={m_b}")
     return SegmentPlan(
         chunk_first=jnp.asarray(chunk_first),
         chunk_count=jnp.asarray(chunk_count),

@@ -90,11 +90,54 @@ def _launch_metric():
     return _LAUNCH_METRIC
 
 
-def account(kind: str, op: str) -> None:
-    """Record one ``kind`` ∈ {"fused", "unfused", "merge"} event on ``op``."""
+def account(kind: str, op: str, grid_steps: Optional[int] = None) -> None:
+    """Record one ``kind`` ∈ {"fused", "unfused", "merge"} event on ``op``.
+    ``grid_steps``: the (output block, chunk) steps a planned launch walks,
+    recorded into the innermost :func:`launch_manifest` of the thread."""
     with _FUSION_LOCK:
         _fusion_sink()[f"{kind}:{op}"] += 1
     _launch_metric().inc(kind=kind, op=op)
+    manifests = _MANIFESTS.get()
+    if grid_steps is not None and manifests:
+        entry = manifests[-1].setdefault(op, [0, 0])
+        entry[0] += 1
+        entry[1] += grid_steps
+
+
+# ---------------------------------------------------------------------------
+# launch manifests — what one traced program launches, for per-execution
+# accounting: the trace records each planned launch once, and whoever runs
+# the executable (the trainer) adds the manifest once per execution
+# ---------------------------------------------------------------------------
+
+_MANIFESTS: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_launch_manifests", default=())
+
+
+@contextlib.contextmanager
+def launch_manifest():
+    """Collect the planned launches traced inside the block (this thread
+    only): yields ``{op: [launches, grid steps walked]}``, where a launch
+    walks out_blocks × ``max_chunks`` (output block, chunk) steps of the
+    plan it was traced with. Feature tiles and the softmax's two passes
+    repeat every step alike and are not counted."""
+    manifest: dict = {}
+    token = _MANIFESTS.set(_MANIFESTS.get() + (manifest,))
+    try:
+        yield manifest
+    finally:
+        _MANIFESTS.reset(token)
+
+
+def _grid_steps(plan, num_segments: int,
+                max_chunks: Optional[int]) -> Optional[int]:
+    """(output block, chunk) steps a launch with ``plan`` walks; None
+    without a segment plan (its chunk counts are then made on the device
+    and unknown here)."""
+    if plan is None or not hasattr(plan, "chunk_count"):
+        return None
+    out_blocks = -(-int(num_segments) // plan.config.s_b)
+    return out_blocks * int(max_chunks or plan.max_chunks)
 
 
 def fusion_counts() -> dict:
@@ -166,7 +209,8 @@ def segment_reduce(x, idx, num_segments: int, reduce: str = "sum",
     config = _resolve_config(config, plan, x.shape[0], num_segments,
                              x.shape[-1], "segment_reduce", tune,
                              io_dtype=x.dtype)
-    account("fused", f"segment_reduce_{reduce}")
+    account("fused", f"segment_reduce_{reduce}",
+            _grid_steps(plan, num_segments, max_chunks))
     if reduce == "mean":
         # the non-gather mean pairs a fused sum launch with a jnp count
         account("unfused", "segment_reduce_mean_count")
@@ -192,7 +236,8 @@ def gather_segment_reduce(h, gather_idx, seg_idx, num_segments: int,
           else f"gather_segment_reduce_{reduce}")
     config = _resolve_config(config, plan, gather_idx.shape[0], num_segments,
                              h.shape[-1], op, tune, io_dtype=h.dtype)
-    account("fused", op if weight is None else f"{op}_weighted")
+    account("fused", op if weight is None else f"{op}_weighted",
+            _grid_steps(plan, num_segments, max_chunks))
     return gather_segment_reduce_pallas(h, gather_idx, seg_idx, num_segments,
                                         weight=weight, reduce=reduce,
                                         config=config, max_chunks=max_chunks,
@@ -219,7 +264,8 @@ def fused_transform_reduce(h, w, gather_idx, seg_idx, num_segments: int,
                              h.shape[-1], "fused_transform_reduce", tune,
                              io_dtype=h.dtype)
     account("fused", "fused_transform_reduce"
-            if weight is None else "fused_transform_reduce_weighted")
+            if weight is None else "fused_transform_reduce_weighted",
+            _grid_steps(plan, num_segments, max_chunks))
     return fused_transform_reduce_pallas(h, w, gather_idx, seg_idx,
                                          num_segments, weight=weight,
                                          reduce=reduce, config=config,
@@ -299,7 +345,8 @@ def segment_softmax(x, idx, num_segments: int,
     feat = int(x.shape[-1]) if x.ndim > 1 else 1
     config = _resolve_config(config, plan, idx.shape[0], num_segments, feat,
                              "segment_softmax", tune, io_dtype=x.dtype)
-    account("fused", "segment_softmax")
+    account("fused", "segment_softmax",
+            _grid_steps(plan, num_segments, max_chunks))
     return segment_softmax_pallas(x, idx, num_segments, config=config,
                                   max_chunks=max_chunks, interpret=interpret,
                                   plan=plan)

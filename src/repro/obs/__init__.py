@@ -9,7 +9,9 @@ Three pillars, zero dependencies beyond the stdlib:
   * **tracing spans** (:mod:`repro.obs.trace`) — ``span("serve.step")``
     context managers building per-request / per-step span trees across
     sample → pad → plan_cache → stamp → device_put → compile → execute,
-    with a ring-buffer trace log and Chrome ``trace_event`` export.
+    with a ring-buffer trace log and Chrome ``trace_event`` export; once
+    jax is loaded each span is also a ``jax.profiler`` annotation, and
+    jax's compile phases land under the span that paid them.
   * **attribution hooks** (:mod:`repro.obs.hooks`) — every jit trace,
     plan-cache miss, PerfDB tune, and bucket probe records a structured
     cause, so ``why_compiled()`` answers "why did step 37 compile?".
@@ -71,6 +73,9 @@ __all__ = [
 OBS_SCHEMA = {
     # kernel launch accounting (trace-time, mirrors fusion_counts)
     "kernel.launches":            ("kind", "op"),
+    # (output block, chunk) grid steps per executed planned launch:
+    # kind = walked (out_blocks × max_chunks) | owned (Σ chunk_count)
+    "kernel.grid_steps":          ("op", "kind"),
     # serving engine (one label value per GNNServer instance)
     "serve.requests":             ("engine",),
     "serve.batches":              ("engine",),
@@ -102,6 +107,10 @@ OBS_SCHEMA = {
     "train.traces":               ("trainer",),
     # attribution counters
     "compile.events":             ("site", "cause"),
+    # jax's compile phases (trace / lower / compile) and the persistent
+    # compilation cache's outcome (hit / miss), from jax.monitoring
+    "compile.phase_s":            ("phase",),
+    "compile.cache":              ("outcome",),
     "autotune.tunes":             ("op", "outcome"),
 }
 

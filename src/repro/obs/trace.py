@@ -20,6 +20,13 @@ process start) loadable in ``chrome://tracing`` / Perfetto;
 :func:`write_chrome_trace` writes it to disk (also wired to
 ``REPRO_TRACE_PATH`` at process exit by :mod:`repro.obs`).
 
+Profiler clock: once :mod:`jax` is imported, every span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a ``jax.profiler``
+trace shows the program's stages on the host plane, on the device
+trace's clock, beside the ops they launched. The first such span also
+registers the compile-phase listeners (:func:`repro.obs.hooks.
+watch_compiles`). This module itself imports only the stdlib.
+
 Disabled mode (``repro.obs.disable()``): ``span`` yields a shared no-op
 span and records nothing — the per-call cost is one flag check.
 """
@@ -30,6 +37,7 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -37,7 +45,8 @@ from typing import Dict, List, Optional
 from repro.obs import registry as _registry
 
 __all__ = ["Span", "span", "current_span", "spans", "reset_spans",
-           "chrome_trace", "write_chrome_trace"]
+           "add_completed", "span_clock", "chrome_trace",
+           "write_chrome_trace"]
 
 _T0 = time.perf_counter()         # process-relative timestamp origin
 
@@ -108,6 +117,13 @@ class _NullSpan:
     name = None
     attrs: Dict = {}
     children: List = []
+    dur_s = 0.0
+
+    @property
+    def t0(self) -> float:
+        """Now, on the span clock: a no-op span starts and ends at once,
+        so a caller that ties the span clock to its own still can."""
+        return time.perf_counter() - _T0
 
     def set(self, **attrs) -> None:
         pass
@@ -122,18 +138,40 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+_ANNOTATION = None     # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` once :mod:`jax` is in
+    ``sys.modules`` (this module never imports it first), else None. The
+    first time it is found, the compile-phase listeners go in too."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        import jax.profiler
+        from repro.obs import hooks
+        hooks.watch_compiles()
+        _ANNOTATION = jax.profiler.TraceAnnotation
+    return _ANNOTATION
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs):
     """Open one timed stage; yields the live :class:`Span` (a shared
-    no-op when observability is disabled)."""
+    no-op when observability is disabled). With :mod:`jax` loaded the
+    stage is also a profiler annotation of the same name."""
     if not _registry._is_enabled():
         yield _NULL
         return
+    annotation = _annotation()
     s = Span(name, attrs)
     parent = _CURRENT.get()
     token = _CURRENT.set(s)
     try:
-        yield s
+        if annotation is None:
+            yield s
+        else:
+            with annotation(name):
+                yield s
     finally:
         s.dur_s = (time.perf_counter() - _T0) - s.t0
         _CURRENT.reset(token)
@@ -142,6 +180,37 @@ def span(name: str, **attrs):
         else:
             with _RING_LOCK:
                 _RING.append(s)
+
+
+def add_completed(name: str, t0: float, dur_s: float,
+                  **attrs) -> Optional[Span]:
+    """Attach a stage that has already ended (``t0`` on the span clock,
+    seconds since process start) as a child of the span open in this
+    context; returns it, or None when no span is open. Children of that
+    span that lie inside the new stage's interval move under it, so a
+    stage reported after its nested stages (jax reports an outer trace
+    after the traces it contains) still forms a tree."""
+    parent = _CURRENT.get()
+    if parent is None:
+        return None
+    s = Span(name, attrs)
+    s.t0, s.dur_s = t0, dur_s
+    # children are in order of their end; those nested in the new stage
+    # ended last, so they are the tail that started after it
+    kids = parent.children
+    i = len(kids)
+    while i and kids[i - 1].t0 >= t0:
+        i -= 1
+    s.children = kids[i:]
+    del kids[i:]
+    kids.append(s)
+    return s
+
+
+def span_clock(wall_s: float) -> float:
+    """A ``time.time()`` reading on the span clock (seconds since the
+    process-relative origin of :attr:`Span.t0`)."""
+    return wall_s - time.time() + time.perf_counter() - _T0
 
 
 def current_span() -> Optional[Span]:

@@ -39,6 +39,7 @@ from typing import Any, NamedTuple, Optional, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -111,6 +112,9 @@ class NodeClassification:
         self._parts: dict = {}     # (id(g), shards) -> (g, part)
         self._pplans: dict = {}    # (id(g), shards, feat, key) -> pplan
         self._buckets: dict = {}   # (static, config, tune) -> canonical aux
+        # id(plan.chunk_count) -> (plan, Σ chunk_count): the grid steps a
+        # bucket plan owns, a host integer kept beside the memoized plan
+        self._owned: dict = {}
 
     @classmethod
     def from_provider(cls, provider, model: str = "gcn", **kw):
@@ -243,7 +247,19 @@ class NodeClassification:
             canon = self._buckets[bkey] = (p0.config, p0.stats)
         cfg, stats = canon
         p = g.make_plan(self.plan_feat, config=cfg)       # memoized on g
+        key = id(p.chunk_count)
+        if key not in self._owned:
+            self._owned[key] = (p, int(np.asarray(p.chunk_count).sum()))
         return dataclasses.replace(p.pin_worst_case(), stats=stats)
+
+    def chunks_owned(self, arrays) -> Optional[int]:
+        """Σ chunk_count of the bucket plan in ``arrays`` (the (output
+        block, chunk) grid steps each of its launches does work in), or
+        None for a plan this task did not build. Every segment launch of
+        the step runs on that one plan, the backward's too."""
+        plan = arrays.get("plan")
+        hit = self._owned.get(id(getattr(plan, "chunk_count", None)))
+        return hit[1] if hit is not None else None
 
     def _bucket_rplan(self, g, static: GraphStatic, config, tune):
         bkey = ("rel", static, config, tune)

@@ -24,6 +24,7 @@ uninterrupted run (tests/test_train.py locks this).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -35,6 +36,7 @@ from repro.checkpoint import checkpoint as ckpt
 from repro.obs import span
 from repro.distributed.fault_tolerance import (ResilientLoop,
                                                ResilientLoopConfig)
+from repro.kernels import ops as kops
 from repro.optim import adamw, schedule
 
 __all__ = ["TrainState", "TrainerConfig", "FitResult", "Trainer", "fit"]
@@ -117,7 +119,12 @@ class Trainer:
                                      vital=True)
         self._m_steps.touch(**self._labels)
         self._m_traces.touch(**self._labels)
+        self._m_grid = reg.counter(
+            "kernel.grid_steps", ("op", "kind"),
+            help="(output block, chunk) kernel grid steps per executed "
+                 "launch: walked, and owned by the plan's chunk counts")
         self._traced_statics: set = set()   # signatures already compiled
+        self._grids: dict = {}   # signature -> launch manifest of its trace
 
     def _note_trace(self, static) -> None:
         """Trace-time side effect: fires once per compile, never on
@@ -177,8 +184,10 @@ class Trainer:
             def loss(p):
                 return task.loss(p, arrays, static, rng, mesh=mesh)
 
-            (l, metrics), grads = jax.value_and_grad(
-                loss, has_aux=True)(state.params)
+            with kops.launch_manifest() as manifest:
+                (l, metrics), grads = jax.value_and_grad(
+                    loss, has_aux=True)(state.params)
+            self._grids[static] = manifest
             lr_scale = lr_scale_fn(state.step, cfg.warmup_steps, cfg.steps)
             new_p, new_o, om = adamw.update(grads, state.opt_state,
                                             state.params, cfg.opt, lr_scale)
@@ -186,6 +195,21 @@ class Trainer:
                     dict(metrics, loss=l, **om))
 
         return jax.jit(step)
+
+    def _count_grid(self, static, arrays) -> None:
+        """Add the launch manifest of ``static``'s trace once for this
+        executed step: grid steps walked, and those the step's plan owns
+        (Σ chunk_count, from the task's memo; None leaves both out)."""
+        grid = self._grids.get(static)
+        owned_of = getattr(self.task, "chunks_owned", None)
+        if not grid or owned_of is None:
+            return
+        owned = owned_of(arrays)
+        if owned is None:
+            return
+        for op, (launches, walked) in grid.items():
+            self._m_grid.inc(walked, op=op, kind="walked")
+            self._m_grid.inc(launches * owned, op=op, kind="owned")
 
     def executable(self, static) -> Callable:
         """The jitted step for a static signature, built on first use."""
@@ -221,8 +245,9 @@ class Trainer:
         history: dict = {}            # step -> loss (replay overwrites)
 
         def step_fn(st, step):
-            with span("train.step", trainer=self._labels["trainer"],
-                      step=int(step)) as root:
+            with _step_annotation(step), \
+                    span("train.step", trainer=self._labels["trainer"],
+                         step=int(step)) as root:
                 with span("train.sample", step=int(step)):
                     batch = self.data.batch(step)
                 with span("train.prepare"):
@@ -236,7 +261,10 @@ class Trainer:
                           static=repr(static)):
                     st, metrics = exe(st, arrays)
                 self._m_steps.inc(**self._labels)
-                loss = float(metrics["loss"])
+                if obs.enabled():
+                    self._count_grid(static, arrays)
+                with span("train.sync"):
+                    loss = float(metrics["loss"])
                 history[step] = loss
                 if cfg.log_every and step % cfg.log_every == 0:
                     print(f"step {step:5d} loss {loss:.4f} "
@@ -255,6 +283,14 @@ class Trainer:
         return FitResult(state=final, losses=losses, start_step=start,
                          traces=self.traces, buckets=self.buckets,
                          events=tuple(loop.events))
+
+
+def _step_annotation(step):
+    """A profiler step marker (TensorBoard's step view finds the steps by
+    it) while observability is on."""
+    if not obs.enabled():
+        return contextlib.nullcontext()
+    return jax.profiler.StepTraceAnnotation("train", step_num=int(step))
 
 
 def fit(task, data, trainer: Optional[TrainerConfig] = None, *,
