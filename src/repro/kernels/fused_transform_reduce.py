@@ -10,7 +10,8 @@ tensor (aggregate-first) ever exists in HBM. Linear reduces only
 makes aggregating at width d_in and transforming per output block
 mathematically identical to transform-then-aggregate.
 
-Schedule (grid = (out_blocks, max_chunks), **no feature tiling**):
+Schedule (grid = (T,), the gather kernel's flat walk of the owned
+(output block, chunk) steps; **no feature tiling**):
 
   * each chunk's H rows are DMA-gathered at full d_in width into VMEM
     staging — one copy per row instead of the ``n_tiles`` copies the
@@ -18,7 +19,7 @@ Schedule (grid = (out_blocks, max_chunks), **no feature tiling**):
     whole contraction dim resident anyway;
   * the PR one-hot matmul accumulates the chunk into an (S_b, d_in) fp32
     VMEM accumulator (same masking convention as ``gather_segment_reduce``);
-  * at the block's last owned chunk the accumulator (mean-normalized if
+  * at the block's last step the accumulator (mean-normalized if
     requested) hits the MXU against the VMEM-resident (d_in, d_out) weight
     tile and the (S_b, d_out) result is written out in the io dtype.
 
@@ -49,7 +50,8 @@ from repro.kernels.layout import (LANES, chunk_stream, compiler_params,
                                   mxu_dot, onehot_t, stream_spec, to_words,
                                   unpack_words, weighted_onehot, word_cols)
 from repro.kernels.segment_reduce import (_resolve_plan, _round_up,
-                                          chunk_metadata)
+                                          chunk_metadata, flat_grid_steps,
+                                          flat_row, flat_step, step_table)
 
 
 def fusable(d_in: int, d_out: int, dtype, config: KernelConfig,
@@ -67,10 +69,10 @@ def fusable(d_in: int, d_out: int, dtype, config: KernelConfig,
     return w_tile + acc + stage + out <= budget
 
 
-def _body(cf_ref, cc_ref, gidx_ref, idx_ref, wt_ref, h_ref, wm_ref, o_ref,
-          xbuf_ref, acc_ref, sem, *scratch, s_b: int, has_weight: bool,
-          reduce: str, io_dtype):
-    b, k = pl.program_id(0), pl.program_id(1)
+def _body(cf_ref, cc_ref, blk_ref, start_ref, gidx_ref, idx_ref, wt_ref,
+          h_ref, wm_ref, o_ref, xbuf_ref, acc_ref, sem, *scratch, s_b: int,
+          has_weight: bool, reduce: str, io_dtype):
+    b, k = flat_step(pl.program_id(0), blk_ref, start_ref)
     cnt_ref = scratch[0] if reduce == "mean" else None
 
     @pl.when(k == 0)
@@ -97,7 +99,8 @@ def _body(cf_ref, cc_ref, gidx_ref, idx_ref, wt_ref, h_ref, wm_ref, o_ref,
                                     keepdims=True)
 
     # in-kernel GEMM once per output block, after its last owned chunk
-    # (blocks owning no chunks fire at k == 0 with a zero accumulator)
+    # (blocks owning no chunks fire on their one step with a zero
+    # accumulator)
     @pl.when(k == jnp.maximum(cc_ref[b], 1) - 1)
     def _transform():
         agg = acc_ref[...]
@@ -147,21 +150,24 @@ def _fused_transform_reduce_impl(h, wm, gather_idx, seg_idx, weight,
     out_blocks = s_pad // s_b
     if max_chunks is None:
         max_chunks = m_pad // m_b
+    n_steps = flat_grid_steps(m_pad // m_b, out_blocks, max_chunks)
+    step_block, block_start = step_table(chunk_count, n_steps)
 
-    def row_map(b, k, cf, cc):
-        return cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0))
+    def row_map(t, *tables):
+        return flat_row(t, *tables)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(out_blocks, max_chunks),
+        num_scalar_prefetch=4,
+        grid=(n_steps,),
         in_specs=[
             stream_spec(m_b, row_map, smem=True),              # gather_idx
             stream_spec(m_b, row_map, smem=False),             # seg_idx
             stream_spec(m_b, row_map, smem=False),             # edge weight
             pl.BlockSpec(memory_space=pl.ANY),                 # H (unblocked)
-            pl.BlockSpec((d_in_pad, d_out_pad), lambda b, k, cf, cc: (0, 0)),
+            pl.BlockSpec((d_in_pad, d_out_pad), lambda t, *_: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((s_b, d_out_pad), lambda b, k, cf, cc: (b, 0)),
+        out_specs=pl.BlockSpec((s_b, d_out_pad),
+                               lambda t, cf, cc, blk, start: (blk[t], 0)),
         scratch_shapes=(
             [pltpu.VMEM((hw.shape[0], m_b, LANES), hw.dtype),  # staged rows
              pltpu.VMEM((s_b, d_in_pad), jnp.float32),         # fp32 segment acc
@@ -177,7 +183,8 @@ def _fused_transform_reduce_impl(h, wm, gather_idx, seg_idx, weight,
         compiler_params=compiler_params(),
         interpret=interpret,
         name="fused_transform_reduce",
-    )(chunk_first, chunk_count, gidx3, idx3, wt3, hw, wmp)
+    )(chunk_first, chunk_count, step_block, block_start, gidx3, idx3, wt3,
+      hw, wmp)
     return out[:num_segments, :d_out]
 
 

@@ -8,6 +8,13 @@ analogue of the fused gather — H stays unblocked in HBM/ANY memory), then the
 same PR (MXU one-hot) / SR (VPU walk) reduction as
 :mod:`repro.kernels.segment_reduce` consumes the staged tile.
 
+Grid: (feature tiles, T). The second axis walks only the owned (output
+block, chunk) steps, block-major and chunk-increasing, through
+scalar-prefetched step tables (:func:`repro.kernels.segment_reduce.step_table`);
+T = min(⌈E/M_b⌉ + out_blocks − 1, out_blocks × max_chunks) depends on
+shapes alone, so a plan pinned to the worst-case ``max_chunks`` walks no
+empty steps.
+
 All three reduces are **single-launch** (paper §VI: generalizing the
 reduction type does not change the schedule):
 
@@ -43,7 +50,8 @@ from repro.kernels.layout import (LANES, chunk_stream, compiler_params,
                                   mxu_dot, onehot_t, stream_spec, to_words,
                                   unpack_words, weighted_onehot, word_ratio)
 from repro.kernels.segment_reduce import (_resolve_plan, _round_up,
-                                          chunk_metadata)
+                                          chunk_metadata, flat_grid_steps,
+                                          flat_row, flat_step, step_table)
 
 
 def _gather_chunk(gidx_ref, h_ref, xbuf_ref, sem, j):
@@ -72,10 +80,11 @@ def _gather_chunk(gidx_ref, h_ref, xbuf_ref, sem, j):
     jax.lax.fori_loop(0, m_b, copy_row, 0, unroll=False)
 
 
-def _pr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
-             xbuf_ref, sem, *scratch, s_b: int, has_weight: bool,
-             reduce: str, io_dtype):
-    b, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+def _pr_body(cf_ref, cc_ref, blk_ref, start_ref, gidx_ref, idx_ref, w_ref,
+             h_ref, o_ref, xbuf_ref, sem, *scratch, s_b: int,
+             has_weight: bool, reduce: str, io_dtype):
+    j = pl.program_id(0)
+    b, k = flat_step(pl.program_id(1), blk_ref, start_ref)
     cnt_ref = scratch[0] if reduce == "mean" else None
 
     @pl.when(k == 0)
@@ -109,10 +118,11 @@ def _pr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
             o_ref[...] = o_ref[...] / jnp.maximum(cnt_ref[...], 1.0)
 
 
-def _sr_body(cf_ref, cc_ref, gidx_ref, idx_ref, w_ref, h_ref, o_ref,
-             xbuf_ref, sem, acc_ref, st_ref, *scratch, s_b: int,
-             has_weight: bool, reduce: str, io_dtype):
-    b, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+def _sr_body(cf_ref, cc_ref, blk_ref, start_ref, gidx_ref, idx_ref, w_ref,
+             h_ref, o_ref, xbuf_ref, sem, acc_ref, st_ref, *scratch,
+             s_b: int, has_weight: bool, reduce: str, io_dtype):
+    j = pl.program_id(0)
+    b, k = flat_step(pl.program_id(1), blk_ref, start_ref)
     cnt_ref, ca_ref = scratch if reduce == "mean" else (None, None)
     # max identity is -inf, matching jax.ops.segment_max on empty segments
     init_val = -jnp.inf if reduce == "max" else 0.0
@@ -229,15 +239,19 @@ def _gather_segment_reduce_impl(h, gather_idx, seg_idx, weight,
     n_tiles = hw.shape[0]
     if max_chunks is None:
         max_chunks = m_pad // m_b
+    n_steps = flat_grid_steps(m_pad // m_b, out_blocks, max_chunks)
+    step_block, block_start = step_table(chunk_count, n_steps)
 
-    def row_map(b, j, k, cf, cc):
-        return cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0))
+    def row_map(j, t, *tables):
+        return flat_row(t, *tables)
 
-    def o_map(b, j, k, cf, cc):
-        return (b, j)
+    def o_map(j, t, cf, cc, blk, start):
+        return (blk[t], j)
 
+    # tile-major, then the flat (block, chunk) steps: each output block
+    # (b, j) is visited in one run of consecutive steps
     common = dict(
-        grid=(out_blocks, n_tiles, max_chunks),
+        grid=(n_tiles, n_steps),
         in_specs=[
             stream_spec(m_b, row_map, smem=True),             # gather_idx
             stream_spec(m_b, row_map, smem=not pr),           # seg_idx
@@ -252,7 +266,7 @@ def _gather_segment_reduce_impl(h, gather_idx, seg_idx, weight,
 
     if pr:
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, **common,
+            num_scalar_prefetch=4, **common,
             scratch_shapes=scratch + cnt_scratch)
         body = functools.partial(_pr_body, s_b=s_b, has_weight=has_weight,
                                  reduce=reduce, io_dtype=h.dtype)
@@ -262,7 +276,7 @@ def _gather_segment_reduce_impl(h, gather_idx, seg_idx, weight,
         if reduce == "mean":
             sr_scratch += cnt_scratch + [pltpu.VMEM((1, 1), jnp.float32)]
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, **common, scratch_shapes=scratch + sr_scratch)
+            num_scalar_prefetch=4, **common, scratch_shapes=scratch + sr_scratch)
         body = functools.partial(_sr_body, s_b=s_b, has_weight=has_weight,
                                  reduce=reduce, io_dtype=h.dtype)
 
@@ -274,7 +288,7 @@ def _gather_segment_reduce_impl(h, gather_idx, seg_idx, weight,
         compiler_params=compiler_params(),
         interpret=interpret,
         name="gather_segment_reduce",
-    )(chunk_first, chunk_count, gidx3, idx3, w3, hw)
+    )(chunk_first, chunk_count, step_block, block_start, gidx3, idx3, w3, hw)
 
     return out[:num_segments, :n].astype(h.dtype)
 

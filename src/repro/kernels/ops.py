@@ -31,7 +31,8 @@ import jax
 from repro.core.config_space import KernelConfig
 from repro.kernels.gather_segment_reduce import gather_segment_reduce_pallas
 from repro.kernels.segment_matmul import segment_matmul_pallas
-from repro.kernels.segment_reduce import segment_reduce_pallas
+from repro.kernels.segment_reduce import (flat_grid_steps,
+                                          segment_reduce_pallas)
 
 
 def _default_interpret() -> bool:
@@ -117,10 +118,10 @@ _MANIFESTS: contextvars.ContextVar = contextvars.ContextVar(
 @contextlib.contextmanager
 def launch_manifest():
     """Collect the planned launches traced inside the block (this thread
-    only): yields ``{op: [launches, grid steps walked]}``, where a launch
-    walks out_blocks × ``max_chunks`` (output block, chunk) steps of the
-    plan it was traced with. Feature tiles and the softmax's two passes
-    repeat every step alike and are not counted."""
+    only): yields ``{op: [launches, grid steps walked]}``, the (output
+    block, chunk) steps of the plan each launch was traced with, as
+    :func:`_grid_steps` counts them. Feature tiles and the softmax's two
+    passes repeat every step alike and are not counted."""
     manifest: dict = {}
     token = _MANIFESTS.set(_MANIFESTS.get() + (manifest,))
     try:
@@ -129,15 +130,20 @@ def launch_manifest():
         _MANIFESTS.reset(token)
 
 
-def _grid_steps(plan, num_segments: int,
-                max_chunks: Optional[int]) -> Optional[int]:
-    """(output block, chunk) steps a launch with ``plan`` walks; None
-    without a segment plan (its chunk counts are then made on the device
-    and unknown here)."""
+def _grid_steps(plan, num_segments: int, max_chunks: Optional[int],
+                flat: bool = False) -> Optional[int]:
+    """(output block, chunk) steps a launch with ``plan`` walks: the flat
+    grid's T for the kernels that walk only owned steps (``flat``), else
+    out_blocks × ``max_chunks``. None without a segment plan (its chunk
+    counts are then made on the device and unknown here)."""
     if plan is None or not hasattr(plan, "chunk_count"):
         return None
     out_blocks = -(-int(num_segments) // plan.config.s_b)
-    return out_blocks * int(max_chunks or plan.max_chunks)
+    max_chunks = int(max_chunks or plan.max_chunks)
+    if flat:
+        return flat_grid_steps(plan.worst_case_chunks, out_blocks,
+                               max_chunks)
+    return out_blocks * max_chunks
 
 
 def fusion_counts() -> dict:
@@ -237,7 +243,7 @@ def gather_segment_reduce(h, gather_idx, seg_idx, num_segments: int,
     config = _resolve_config(config, plan, gather_idx.shape[0], num_segments,
                              h.shape[-1], op, tune, io_dtype=h.dtype)
     account("fused", op if weight is None else f"{op}_weighted",
-            _grid_steps(plan, num_segments, max_chunks))
+            _grid_steps(plan, num_segments, max_chunks, flat=True))
     return gather_segment_reduce_pallas(h, gather_idx, seg_idx, num_segments,
                                         weight=weight, reduce=reduce,
                                         config=config, max_chunks=max_chunks,
@@ -265,7 +271,7 @@ def fused_transform_reduce(h, w, gather_idx, seg_idx, num_segments: int,
                              io_dtype=h.dtype)
     account("fused", "fused_transform_reduce"
             if weight is None else "fused_transform_reduce_weighted",
-            _grid_steps(plan, num_segments, max_chunks))
+            _grid_steps(plan, num_segments, max_chunks, flat=True))
     return fused_transform_reduce_pallas(h, w, gather_idx, seg_idx,
                                          num_segments, weight=weight,
                                          reduce=reduce, config=config,

@@ -66,6 +66,50 @@ def chunk_metadata(idx, num_segments: int, s_b: int, m_b: int, m_pad: int):
 
 
 # ---------------------------------------------------------------------------
+# flat grid over the owned (block, chunk) steps (jit-safe; length static)
+# ---------------------------------------------------------------------------
+
+def flat_grid_steps(n_chunks: int, out_blocks: int, max_chunks: int) -> int:
+    """Static length T of the flat grid over ``n_chunks = m_pad // m_b``
+    input chunks. Sorted ids make consecutive blocks' chunk ranges overlap
+    in at most one chunk, so the owned steps, plus one for each block that
+    owns none, number at most ``n_chunks + out_blocks - 1``; the ``min``
+    keeps a tight plan from walking more than its (out_blocks, max_chunks)
+    grid would."""
+    return min(n_chunks + out_blocks - 1, out_blocks * max_chunks)
+
+
+def step_table(chunk_count, n_steps: int):
+    """Block-major tables of the flat grid, from the plan's chunk counts.
+
+    Block b takes ``max(chunk_count[b], 1)`` consecutive steps from
+    ``block_start[b]`` on (one for a block that owns nothing: its init, or
+    the fused kernel's transform). Returns ``(step_block, block_start)``
+    of shapes (n_steps,) and (out_blocks,): steps past the last block's
+    are inert, mapped to the last block beyond its last chunk."""
+    steps = jnp.maximum(chunk_count, 1)
+    block_start = (jnp.cumsum(steps) - steps).astype(jnp.int32)
+    step_block = jnp.searchsorted(block_start,
+                                  jnp.arange(n_steps, dtype=jnp.int32),
+                                  side="right") - 1
+    return step_block.astype(jnp.int32), block_start
+
+
+def flat_step(t, step_block, block_start):
+    """(output block, its chunk ordinal k) of flat grid step ``t``."""
+    b = step_block[t]
+    return b, t - block_start[b]
+
+
+def flat_row(t, cf, cc, step_block, block_start):
+    """Input chunk that flat step ``t`` reads: its block's k-th, held at
+    the block's last (a block owning none reads its first; an inert step
+    re-reads the last block's last chunk, so it fetches nothing new)."""
+    b, k = flat_step(t, step_block, block_start)
+    return cf[b] + jnp.minimum(k, jnp.maximum(cc[b] - 1, 0))
+
+
+# ---------------------------------------------------------------------------
 # kernel bodies
 # ---------------------------------------------------------------------------
 

@@ -103,6 +103,167 @@ def test_gather_segment_reduce_kernel_mean_max_gapped():
         np.testing.assert_allclose(ga[mask], wa[mask], rtol=3e-4, atol=3e-4)
 
 
+# ---------------------------------------------------------------------------
+# the flat grid of the gather and fused kernels: only owned (block, chunk)
+# steps are walked, at s_b = 64 and m_b = 128 here
+# ---------------------------------------------------------------------------
+
+FLAT_CASES = ["hub", "hub_tail", "empty_runs", "gapped", "ragged", "short",
+              "none"]
+
+
+def _flat_case(case, rng):
+    """(sorted segment ids, num_segments) on which the step tables are
+    easy to get wrong."""
+    if case == "hub":                    # one segment owns every chunk
+        return np.full(1000, 70, np.int32), 300
+    ids, s = {
+        # a hub beside many short segments
+        "hub_tail": lambda: (np.concatenate([np.full(700, 5),
+                                             rng.integers(0, 300, 300)]),
+                             300),
+        # blocks 1-3 own nothing
+        "empty_runs": lambda: (np.concatenate([rng.integers(0, 40, 300),
+                                               rng.integers(250, 300, 300)]),
+                               300),
+        "gapped": lambda: (rng.choice(np.arange(0, 500, 7), 200), 500),
+        "ragged": lambda: (rng.integers(0, 130, 400), 130),  # 130 % 64 != 0
+        "short": lambda: (rng.integers(0, 60, 50), 60),      # E < m_b
+        "none": lambda: (np.zeros(0, np.int32), 70),         # E = 0
+    }[case]()
+    return np.sort(ids).astype(np.int32), s
+
+
+def _random_index(rng):
+    """Sorted ids whose skew ranges from one hub to uniform, at a random
+    tiling."""
+    s = int(rng.integers(1, 400))
+    m = int(rng.integers(0, 3000))
+    p = rng.dirichlet(np.full(s, rng.choice([0.02, 1.0, 30.0])))
+    ids = np.sort(rng.choice(s, m, p=p)).astype(np.int32)
+    return ids, s, int(rng.choice([8, 64, 128])), int(rng.choice([8, 128,
+                                                                  512]))
+
+
+def _check_step_table(idx, s, s_b, m_b):
+    from repro.kernels.segment_reduce import (chunk_metadata,
+                                              flat_grid_steps, flat_row,
+                                              flat_step, step_table)
+    m_pad = max(-(-len(idx) // m_b), 1) * m_b
+    n_chunks = m_pad // m_b
+    idxp = np.full(m_pad, s, np.int32)
+    idxp[:len(idx)] = idx
+    cf, cc = (np.asarray(a) for a in chunk_metadata(jnp.asarray(idxp), s,
+                                                     s_b, m_b, m_pad))
+    ob = len(cc)
+    want = [(b, int(cf[b]) + i) for b in range(ob) for i in range(cc[b])]
+    for max_chunks in {max(1, int(cc.max())), n_chunks}:   # tight, pinned
+        t_len = flat_grid_steps(n_chunks, ob, max_chunks)
+        assert t_len <= min(n_chunks + ob - 1, ob * max_chunks)
+        blk, start = (np.asarray(a) for a in step_table(jnp.asarray(cc),
+                                                        t_len))
+        t = np.arange(t_len)
+        b, k = (np.asarray(a) for a in flat_step(t, blk, start))
+        row = np.asarray(flat_row(t, cf, cc, blk, start))
+        assert np.all((b >= 0) & (b < ob) & (k >= 0))
+        owned = k < cc[b]
+        empty = ~owned & (k == 0)
+        inert = np.flatnonzero(~owned & ~empty)
+        # block-major, chunk-increasing, each owned pair exactly once
+        assert list(zip(b[owned].tolist(), row[owned].tolist())) == want
+        # one step for each block that owns nothing
+        assert b[empty].tolist() == np.flatnonzero(cc == 0).tolist()
+        # inert steps only at the end, re-reading the last block's last row
+        assert inert.tolist() == list(range(t_len - len(inert), t_len))
+        assert np.all(b[inert] == ob - 1)
+        assert np.all(row[inert] == cf[-1] + max(cc[-1] - 1, 0))
+
+
+@pytest.mark.parametrize("case", FLAT_CASES + ["random"])
+def test_flat_step_table_covers_each_owned_step_once(case):
+    rng = np.random.default_rng((FLAT_CASES + ["random"]).index(case))
+    if case == "random":
+        draws = [_random_index(rng) for _ in range(15)]
+    else:
+        draws = [_flat_case(case, rng) + (64, 128)]
+    for idx, s, s_b, m_b in draws:
+        _check_step_table(idx, s, s_b, m_b)
+
+
+def _flat_inputs(case, n=20, v=90):
+    rng = np.random.default_rng(FLAT_CASES.index(case))
+    idx, s = _flat_case(case, rng)
+    m = len(idx)
+    return (jnp.asarray(idx), s,
+            jnp.asarray(rng.integers(0, v, m).astype(np.int32)),
+            jnp.asarray(rng.standard_normal((v, n)), jnp.float32),
+            jnp.asarray(rng.standard_normal(m), jnp.float32))
+
+
+def _assert_close_where_finite(got, want, tol):
+    ga, wa = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    mask = np.isfinite(wa)                  # max keeps -inf on empty ones
+    assert np.array_equal(np.isfinite(ga), mask)
+    np.testing.assert_allclose(ga[mask], wa[mask], **tol)
+
+
+@pytest.mark.parametrize("case", FLAT_CASES)
+@pytest.mark.parametrize("sched", SCHEDS)
+@pytest.mark.parametrize("reduce", ["sum", "mean", "max"])
+def test_gather_segment_reduce_flat_grid(case, sched, reduce):
+    """Skewed, gapped, ragged, short and empty indices against the oracle,
+    weighted or not in fp32 and bf16; a plan pinned to the worst case
+    gives the tight plan's output bit for bit."""
+    from repro.core.plan import make_plan
+    seg, s, gidx, h32, w32 = _flat_inputs(case)
+    plan = make_plan(np.asarray(seg), s, feat=h32.shape[1],
+                     config=KernelConfig(sched, 64, 128, 128, 8))
+    # weighted in fp32 on every other case, in bf16 on the rest
+    odd = FLAT_CASES.index(case) % 2
+    for dtype, weighted in ((np.float32, not odd), (jnp.bfloat16, odd)):
+        h, w = h32.astype(dtype), w32.astype(dtype) if weighted else None
+        got = kops.gather_segment_reduce(h, gidx, seg, s, weight=w,
+                                         reduce=reduce, plan=plan,
+                                         interpret=True)
+        want = ref.gather_segment_reduce(
+            h.astype(jnp.float32), gidx, seg, s,
+            weight=None if w is None else w.astype(jnp.float32),
+            reduce=reduce)
+        _assert_close_where_finite(got, want, _tol(dtype))
+        if dtype == np.float32:
+            pinned = kops.gather_segment_reduce(
+                h, gidx, seg, s, weight=w, reduce=reduce,
+                plan=plan.pin_worst_case(), interpret=True)
+            np.testing.assert_array_equal(np.asarray(pinned),
+                                          np.asarray(got))
+
+
+@pytest.mark.parametrize("case", FLAT_CASES)
+@pytest.mark.parametrize("reduce", ["sum", "mean"])
+def test_fused_transform_reduce_flat_grid(case, reduce):
+    """The fused kernel on the same indices: every block, empty ones
+    included, gets its transform; pinned and tight plans agree bit for
+    bit."""
+    from repro.core.plan import make_plan
+    seg, s, gidx, h, w = _flat_inputs(case)
+    wm = jnp.asarray(np.random.default_rng(3).standard_normal((20, 24)),
+                     jnp.float32)
+    plan = make_plan(np.asarray(seg), s, feat=h.shape[1],
+                     config=KernelConfig("PR", 64, 128, 128, 8))
+    got = kops.fused_transform_reduce(h, wm, gidx, seg, s, weight=w,
+                                      reduce=reduce, plan=plan,
+                                      interpret=True)
+    want = ref.gather_segment_reduce(h, gidx, seg, s, weight=w,
+                                     reduce=reduce) @ wm
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_tol(np.float32))
+    pinned = kops.fused_transform_reduce(h, wm, gidx, seg, s, weight=w,
+                                         reduce=reduce,
+                                         plan=plan.pin_worst_case(),
+                                         interpret=True)
+    np.testing.assert_array_equal(np.asarray(pinned), np.asarray(got))
+
+
 def test_gather_segment_reduce_rejects_unknown_reduce():
     h = jnp.zeros((4, 8))
     idx = jnp.zeros(4, jnp.int32)

@@ -93,6 +93,17 @@ def test_nested_traces_form_a_tree_under_the_outer_trace():
     assert any(c.name == "jax.trace" for c in outer.children)
 
 
+def _walked(op, plan):
+    """Grid steps one launch of ``op`` walks: the gather and fused kernels
+    walk the flat grid of owned (block, chunk) steps, the others every
+    (block, chunk) pair up to ``max_chunks``."""
+    out_blocks = int(plan.chunk_count.shape[0])
+    if op.startswith(("gather_segment_reduce", "fused_transform_reduce")):
+        return min(plan.worst_case_chunks + out_blocks - 1,
+                   out_blocks * plan.max_chunks)
+    return out_blocks * plan.max_chunks
+
+
 def test_grid_steps_count_each_executed_launch():
     # a plan with several output blocks and chunks, pinned to the worst
     # case as the trainer pins it
@@ -104,15 +115,33 @@ def test_grid_steps_count_each_executed_launch():
     arrays, static = trainer.task.prepare(g, config=cfg)
     plan = arrays["plan"]
     owned = int(np.asarray(plan.chunk_count).sum())
-    walked = int(plan.chunk_count.shape[0]) * plan.max_chunks
-    assert owned < walked
+    assert owned < int(plan.chunk_count.shape[0]) * plan.max_chunks
     assert trainer.task.chunks_owned(arrays) == owned
     manifest = trainer._grids[static]
     grid = obs.get_registry().get("kernel.grid_steps")
     assert manifest
+    assert any(op.startswith("gather_segment_reduce") for op in manifest)
     for op, (launches, _) in manifest.items():
+        walked = _walked(op, plan)
+        assert owned <= walked
         assert grid.value(op=op, kind="walked") == 2 * launches * walked
         assert grid.value(op=op, kind="owned") == 2 * launches * owned
+
+
+def test_pinned_plan_walks_a_grid_the_plan_owns():
+    # thirty-two chunks over two output blocks: the pinned (block, chunk)
+    # grid would walk 64 steps a launch, the flat grid at most 33
+    cfg = KernelConfig(schedule="SR", s_b=128, n_b=128, m_b=128)
+    _trainer(nodes=256, edges=4096, config=cfg).fit()
+    from benchmarks.chip.program_obs import counter_by_label
+    grid = obs.get_registry().get("kernel.grid_steps")
+    flat = [op for op in counter_by_label("kernel.grid_steps", "op")
+            if op.startswith(("gather_segment_reduce",
+                              "fused_transform_reduce"))]
+    assert flat
+    owned = sum(grid.value(op=op, kind="owned") for op in flat)
+    walked = sum(grid.value(op=op, kind="walked") for op in flat)
+    assert owned >= 0.9 * walked
 
 
 def test_disabled_records_nothing_and_opens_no_annotation(monkeypatch):
