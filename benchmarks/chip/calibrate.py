@@ -5,7 +5,8 @@ fault, at a training cell's own size. Not part of a benchmark run.
     python benchmarks/chip/calibrate.py --workload gcn-arxiv.train-powerlaw \
         --seeds 1 2 3
 
-For each seed, put beside the float32 reference (matmuls at ``highest``):
+For each seed, put beside the cell's float32 reference (``cell.model``,
+matmuls at ``highest``):
 
 * ``control`` — the reference with every matmul, forward and backward, in
   three bf16 passes (``high``, the precision below the configuration's);
@@ -27,16 +28,17 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
 def train_readings(cell, seed: int) -> list:
-    from benchmarks.chip import compare, graphs, reference
+    from benchmarks.chip import compare, graphs
+    from benchmarks.chip.drivers.train import CHECKED_STEPS
     cfg = cell.config
     g = graphs.make_graph(cell.traffic["law"], cfg["num_nodes"],
                           cfg["num_edges"], cfg["num_features"],
                           cfg["num_classes"], graphs.rng_for(seed, "graph"))
-    ref = reference.train(cfg, g, seed)
+    ref = cell.model.train(cfg, g, seed, steps=CHECKED_STEPS)
     out = []
     for name, kw in (("control", {"precision": "high"}),
                      ("half_batch", {"rows": g.num_nodes // 2})):
-        other = reference.train(cfg, g, seed, **kw)
+        other = cell.model.train(cfg, g, seed, steps=CHECKED_STEPS, **kw)
         out.append({"reading": name, **compare.train_numbers(other, ref)})
     return out
 

@@ -34,6 +34,12 @@ def load_limits(cell: str, directory: Path = HERE / "limits") -> dict:
             if not k.startswith("_")}
 
 
+def leaf_norms(tree) -> dict:
+    """``{"<layer>.<name>": float64 norm}`` of a list-of-dicts tree."""
+    return {f"{i}.{k}": float(np.linalg.norm(np.asarray(a, np.float64)))
+            for i, layer in enumerate(tree) for k, a in layer.items()}
+
+
 def _leaf_gaps(got: dict, ref: dict, keep=None) -> float:
     names = [k for k in ref if keep is None or k in keep]
     if set(got) != set(ref):
@@ -44,8 +50,8 @@ def _leaf_gaps(got: dict, ref: dict, keep=None) -> float:
 
 
 def train_numbers(got: dict, ref: dict) -> dict:
-    """``got`` and ``ref`` as :func:`benchmarks.chip.reference.train`
-    returns them (``losses``, ``grad_norms``, ``delta_norms``)."""
+    """``got`` and ``ref`` as a cell's reference ``train`` returns them
+    (``losses``, ``grad_norms``, ``delta_norms``)."""
     losses = [abs(a - b) / max(abs(b), 1e-30)
               for a, b in zip(got["losses"], ref["losses"], strict=True)]
     g_ref = ref["grad_norms"]

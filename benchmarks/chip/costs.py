@@ -8,10 +8,11 @@ Two levels:
   streams read once, the output written once. Padding the kernel adds for
   its own layout (rows widened to a 128-lane tile, grid steps it skips) is
   not counted, so a share of the roofline shows that waste;
-* per training step (:func:`train_step_flops`, with
-  :func:`forward_flops`): the model's matmuls and aggregation arithmetic,
-  each counted once, in the cheaper of the two orders a layer may take;
-  nothing recomputed.
+* per training step of GCN and GraphSAGE (:func:`train_step_flops`, with
+  :func:`forward_flops`; ``references/gcn.py`` and ``sage.py`` hand it
+  on, and another model's reference module brings its own): the model's
+  matmuls and aggregation arithmetic, each counted once, in the cheaper of
+  the two orders a layer may take; nothing recomputed.
 
 :class:`LaunchRecorder` notes the logical shapes of each kernel launch while
 a step is traced: the wrappers of ``repro.kernels.ops`` are called through
@@ -31,10 +32,10 @@ INDEX_BYTES = 4            # int32 gather and segment ids
 class Launch:
     """One kernel launch as the program called it."""
     kernel: str
-    num_rows: int          # V: rows of the gathered operand
+    num_rows: int          # V: rows of the gathered operand (softmax: E)
     num_edges: int         # E
-    num_segments: int      # S: output rows
-    d_in: int              # width gathered and reduced
+    num_segments: int      # S: output rows (softmax: segments)
+    d_in: int              # width gathered and reduced (softmax: heads)
     d_out: int             # output width (== d_in without a transform)
     weighted: bool
     io_bytes: int          # bytes per value of the gathered operand
@@ -51,9 +52,29 @@ class Cost:
                    self.bytes / peaks.hbm_bytes_per_s)
 
 
+SOFTMAX_FLOPS = 5          # max, subtract, exp, sum, divide per element
+
+
 def kernel_cost(launch: Launch) -> Cost:
+    """The least work of one launch, by kernel:
+
+    * ``gather_segment_reduce``: one multiply (weighted) and one add per
+      edge and feature; the distinct source rows, the gather and segment
+      ids (and the weights) read once, the output written once;
+    * ``fused_transform_reduce``: the same, plus the transform's
+      2 · S · d_in · d_out and its weight read once;
+    * ``segment_softmax`` over E logits of H heads (``num_edges`` × d_in):
+      a max-shifted softmax, one operation per element for each of the
+      max, the subtraction, the exponential, the sum and the division
+      (5 · E · H); the logits and the segment ids read once, the weights
+      written once.
+
+    Any other kernel raises ``KeyError``."""
     e, v, s = launch.num_edges, launch.num_rows, launch.num_segments
     d_in, d_out, b = launch.d_in, launch.d_out, launch.io_bytes
+    if launch.kernel == "segment_softmax":
+        return Cost(SOFTMAX_FLOPS * e * d_in,
+                    e * d_in * b + e * INDEX_BYTES + e * d_out * b)
     per_edge = 2 if launch.weighted else 1
     flops = per_edge * e * d_in
     streams = e * (2 * INDEX_BYTES + (b if launch.weighted else 0))
@@ -137,8 +158,15 @@ def _fused_launch(h, w, gather_idx, seg_idx, num_segments, weight=None,
                   _itemsize(h))
 
 
+def _softmax_launch(x, idx, num_segments, **_) -> Launch:
+    heads = int(x.shape[-1]) if x.ndim > 1 else 1
+    return Launch("segment_softmax", int(x.shape[0]), int(idx.shape[0]),
+                  int(num_segments), heads, heads, False, _itemsize(x))
+
+
 _RECORDERS = {"gather_segment_reduce": _gather_launch,
-              "fused_transform_reduce": _fused_launch}
+              "fused_transform_reduce": _fused_launch,
+              "segment_softmax": _softmax_launch}
 
 
 class LaunchRecorder:

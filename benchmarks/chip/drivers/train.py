@@ -7,7 +7,11 @@ its state, and drives it through the first three steps: the first compiles
 checks. The window then calls the same trainer, one step per
 ``Trainer.fit`` call, until ``seconds`` have passed; each step ends when the
 trainer reads its loss back. After the window the program's state is
-dropped and the plain reference runs the same three steps.
+dropped and the cell's plain reference (``cell.model``, found by the
+configuration's ``reference``) runs the same three steps.
+
+The configuration's optional ``model_options`` reach the program's model
+as keywords, in :func:`build` and :func:`program.to_params`.
 
 Traffic keys: ``driver`` ("train") and ``law`` (see :func:`graphs.make_graph`).
 """
@@ -21,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.chip import compare, costs, graphs, program, reference
+from benchmarks.chip import compare, costs, graphs, program
 from benchmarks.chip.harness import Outcome, program_spans
 
 CHECKED_STEPS = 3
@@ -46,7 +50,8 @@ def build(cfg: dict, graph, params):
     task = NodeClassification(model=cfg["model"], d_in=cfg["num_features"],
                               hidden=cfg["hidden_channels"],
                               num_classes=cfg["num_classes"],
-                              num_layers=cfg["num_layers"], impl="pallas")
+                              num_layers=cfg["num_layers"], impl="pallas",
+                              **cfg.get("model_options", {}))
     # Adam as the configuration states it: no weight decay, no clipping
     opt = adamw.AdamWConfig(lr=cfg["lr"], b1=cfg["adam_b1"],
                             b2=cfg["adam_b2"], eps=cfg["adam_eps"],
@@ -61,14 +66,17 @@ def build(cfg: dict, graph, params):
     return trainer, state
 
 
-def plan_counts(trainer, graph) -> list:
-    """(Σ chunk_count, out_blocks, max_chunks) of the plan the step runs
-    with, as the trainer's task prepares it (memoized, so the same one)."""
+def plan_counts(trainer, graph) -> dict:
+    """Chunks owned (Σ chunk_count), output blocks, ``max_chunks`` and input
+    chunks (``worst_case_chunks``) of the plan the step runs with, as the
+    trainer's task prepares it (memoized, so the same one)."""
     arrays, _ = trainer.task.prepare(graph, plan=trainer.plan,
                                      config=trainer.config, tune=trainer.tune)
     plan = arrays["plan"]
     counts = np.asarray(plan.chunk_count)
-    return [(int(counts.sum()), int(counts.size), int(plan.max_chunks))]
+    return {"chunks": int(counts.sum()), "out_blocks": int(counts.size),
+            "max_chunks": int(plan.max_chunks),
+            "input_chunks": int(plan.worst_case_chunks)}
 
 
 class GcPauses:
@@ -112,7 +120,7 @@ def run(cell, *, seed: int, seconds: float, tracer, t0: float) -> Outcome:
                           cfg["num_edges"], cfg["num_features"],
                           cfg["num_classes"], graphs.rng_for(seed, "graph"))
     t_graph = time.perf_counter()
-    params = reference.init_params(cfg, seed)
+    params = cell.model.init_params(cfg, seed)
     p0 = [{k: np.asarray(a) for k, a in layer.items()} for layer in params]
     pg = program.to_graph(g, f"{cell.name}-{seed}")
     trainer, state = build(cfg, pg, params)
@@ -132,11 +140,11 @@ def run(cell, *, seed: int, seconds: float, tracer, t0: float) -> Outcome:
     b1 = cfg["adam_b1"]
     got = {"losses": losses,
            "grad_norms": {k: v / (1.0 - b1) for k, v in
-                          reference.leaf_norms(mu1).items()},
-           "delta_norms": reference.leaf_norms(
+                          compare.leaf_norms(mu1).items()},
+           "delta_norms": compare.leaf_norms(
                [{k: a - p0[i][k] for k, a in layer.items()}
                 for i, layer in enumerate(program.from_tree(state.params))])}
-    plans = plan_counts(trainer, pg)
+    plan = plan_counts(trainer, pg)
     traces_before = trainer.traces
 
     nonfinite, ends = 0, []
@@ -158,7 +166,7 @@ def run(cell, *, seed: int, seconds: float, tracer, t0: float) -> Outcome:
     peak = program.memory_peak_bytes()
     del trainer, state, res
     gc.collect()
-    ref = reference.train(cfg, g, seed, steps=CHECKED_STEPS)
+    ref = cell.model.train(cfg, g, seed, steps=CHECKED_STEPS)
     numbers = compare.train_numbers(got, ref)
 
     notes = [
@@ -170,7 +178,7 @@ def run(cell, *, seed: int, seconds: float, tracer, t0: float) -> Outcome:
         f"setup_s={setup_s:.6f}",
         f"[setup] kernel launches per step: "
         f"{[(l.kernel, l.num_edges, l.d_in, l.d_out) for l in launches]}",
-        f"[setup] plan (chunks, out_blocks, max_chunks)={plans}",
+        f"[setup] plan {plan}",
         f"[window] steps={steps} window_s={window_s:.6f} "
         f"retraces={retraces} nonfinite={nonfinite}",
         f"[window] step_s="
@@ -179,11 +187,11 @@ def run(cell, *, seed: int, seconds: float, tracer, t0: float) -> Outcome:
         f"step_self_s={parts['train.step']} gc={collections.pauses}",
         f"[check] losses={losses} ref={ref['losses']}",
     ]
-    flops = costs.train_step_flops(cfg, g.num_nodes, g.num_edges)
+    flops = cell.model.train_step_flops(cfg, g.num_nodes, g.num_edges)
     return Outcome(
         attempted=steps, failed=nonfinite,
         end_to_end={"train_step_s": window_s / steps, "setup_s": setup_s},
         numbers=numbers, memory_peak_bytes=peak,
         layer={"window_s": window_s, "launch_groups": [(launches, steps)],
-               "plans": plans, "flops": flops * steps, "span_units": steps},
+               "plan": plan, "flops": flops * steps, "span_units": steps},
         notes=notes)

@@ -3,7 +3,13 @@
 ``BENCHMARK.json`` at the root of the checkout names the cells. For a cell
 ``<config>.<traffic>`` the harness reads:
 
-* the configuration file that ``configs[*].file`` names;
+* the configuration file that ``configs[*].file`` names. Its
+  ``reference`` key names the cell's model, ``references/<reference>.py``:
+  the plain reference with ``init_params(cfg, seed)``, ``train(cfg, graph,
+  seed, *, steps, precision="highest", rows=None)`` (what
+  :func:`compare.train_numbers` takes) and ``train_step_flops(cfg, nodes,
+  edges)``. An optional ``model_options`` object goes to the program's
+  model as keywords (``heads`` for GAT);
 * ``traffic/<traffic>.json`` — parameters for the generators, and the
   ``driver`` (``drivers/<driver>.py``) that drives the program's entry
   point with them;
@@ -12,8 +18,8 @@
   ``read(ctx)`` that returns a number, or None where there is nothing to
   read (the metric is then left out of the line).
 
-A new cell, configuration, traffic mix or metric is a new file and a new
-entry; no file here changes.
+A new cell, configuration, model, traffic mix or metric is a new file and
+a new entry; no file here changes.
 """
 from __future__ import annotations
 
@@ -29,6 +35,7 @@ import re
 import shutil
 import sys
 import time
+import types
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -45,6 +52,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    model: types.ModuleType   # references/<reference>.py
     traffic: dict
     end_to_end: list          # BENCHMARK.json entries this cell reports
     per_layer: list
@@ -70,12 +78,15 @@ def find_cell(bench: dict, name: str, *, root: Path = ROOT,
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     config = json.loads((root / configs[w["config"]]["file"]).read_text())
+    if "reference" not in config:
+        raise CellError(f"configuration {w['config']!r} names no reference")
     traffic = json.loads((here / "traffic" / f"{w['traffic']}.json")
                          .read_text())
     e2e = [m for m in bench["end_to_end"] if _reports(m, name)]
     names = {m["name"] for m in e2e}
     layer = [m for m in bench["per_layer"] if _reports(m, name, names)]
     return Cell(name=name, chips=int(w["chips"]), config=config,
+                model=load_model(config["reference"], here),
                 traffic=traffic, end_to_end=e2e, per_layer=layer,
                 limits=compare.load_limits(name, here / "limits"))
 
@@ -85,16 +96,35 @@ def load_driver(traffic: dict):
         f"benchmarks.chip.drivers.{traffic['driver']}")
 
 
-def load_metric(name: str, here: Path = HERE):
-    """The ``read`` function of ``metrics/<name>.py``."""
-    path = here / "metrics" / f"{name}.py"
-    mod_name = "benchmarks_chip_metric_" + re.sub(r"\W", "_", name)
+def _load_file(kind: str, name: str, path: Path):
+    mod_name = f"benchmarks_chip_{kind}_" + re.sub(r"\W", "_", name)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     if spec is None or not path.exists():
-        raise CellError(f"no reader for metric {name!r} at {path}")
+        raise CellError(f"no {kind} {name!r} at {path}")
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_metric(name: str, here: Path = HERE):
+    """The ``read`` function of ``metrics/<name>.py``."""
+    return _load_file("metric", name, here / "metrics" / f"{name}.py").read
+
+
+MODEL_FUNCTIONS = ("init_params", "train", "train_step_flops")
+
+
+def load_model(name: str, here: Path = HERE):
+    """The module ``references/<name>.py``: a model's plain reference and
+    its flops, as :data:`MODEL_FUNCTIONS` name them."""
+    module = _load_file("reference", name,
+                        here / "references" / f"{name}.py")
+    missing = [f for f in MODEL_FUNCTIONS if not callable(getattr(module,
+                                                                  f, None))]
+    if missing:
+        raise CellError(f"reference {name!r} at {module.__file__} lacks "
+                        f"{missing}")
+    return module
 
 
 # ---------------------------------------------------------------------------

@@ -9,13 +9,15 @@ import numpy as np
 
 def to_params(params: list, cfg: dict):
     """The benchmark's weights in the program's parameter tree (a list of
-    ``{name: P}``), shaped as ``repro.models.gnn.init`` shapes it."""
+    ``{name: P}``), shaped as ``repro.models.gnn.init`` shapes it with the
+    configuration's ``model_options``."""
     from repro.models import gnn
     from repro.models.params import P
     shapes = jax.eval_shape(
         lambda: gnn.init(jax.random.PRNGKey(0), cfg["model"],
                          cfg["num_features"], cfg["hidden_channels"],
-                         cfg["num_classes"], cfg["num_layers"]))
+                         cfg["num_classes"], cfg["num_layers"],
+                         **cfg.get("model_options", {})))
     out = []
     for mine, theirs in zip(params, shapes, strict=True):
         if set(mine) != set(theirs):

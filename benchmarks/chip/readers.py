@@ -3,7 +3,7 @@
 
 ``ctx`` holds the traced run's reduced trace (``summary``), the program's
 spans in the window (``spans``), the chip's ``peaks`` and what the driver
-counted (``window_s``, ``launch_groups``, ``plans``, ``flops``,
+counted (``window_s``, ``launch_groups``, ``plan``, ``flops``,
 ``span_units``). A reader that finds nothing to read returns
 None, never 0.
 """
@@ -49,12 +49,3 @@ def mfu(ctx) -> float | None:
         return None
     return 100.0 * flops / window / (ctx["peaks"].flops_per_s
                                      * ctx["cell"].chips)
-
-
-def plan_useful_share(ctx) -> float | None:
-    plans = ctx.get("plans") or ()
-    grid = sum(blocks * max_chunks for _, blocks, max_chunks in plans)
-    if grid <= 0:
-        return None
-    return 100.0 * sum(chunks for chunks, _, _ in plans) / grid
-

@@ -18,7 +18,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from benchmarks.chip.compare import leaf_norms
 
 MODELS = ("gcn", "sage")
 
@@ -164,12 +165,6 @@ def _adam_step(params, m, v, t, x, src, dst, labels, *, model, num_nodes,
         lambda p, a, b: p - lr * (a / bc1) / (jnp.sqrt(b / bc2) + eps),
         params, m, v)
     return params, m, v, t, value, grads
-
-
-def leaf_norms(tree) -> dict:
-    """``{"<layer>.<name>": float64 norm}`` of a list-of-dicts tree."""
-    return {f"{i}.{k}": float(np.linalg.norm(np.asarray(a, np.float64)))
-            for i, layer in enumerate(tree) for k, a in layer.items()}
 
 
 def train(cfg: dict, graph, seed: int, *, steps: int = 3,

@@ -11,7 +11,8 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from benchmarks.chip import compare, graphs, harness, reference, tiny  # noqa: E402
+from benchmarks.chip import compare, graphs, harness, tiny  # noqa: E402
+from benchmarks.chip.drivers.train import CHECKED_STEPS  # noqa: E402
 
 CELLS = [w["name"] for w in harness.load_benchmark()["workloads"]]
 
@@ -74,17 +75,18 @@ def test_half_the_batch_left_out_fails(name, tmp_path, capsys, monkeypatch):
 @pytest.mark.parametrize("seed", [1, 2, 3])
 @pytest.mark.parametrize("name", CELLS)
 def test_the_lower_precision_control_fails(name, seed):
-    # the reference with every matmul in three bf16 passes (``high``, the
-    # precision below the configuration's ``highest``), in the program's
-    # place, against the float32 reference: the control whose chip
-    # readings set the limits
+    # the cell's reference with every matmul in three bf16 passes
+    # (``high``, the precision below the configuration's ``highest``), in
+    # the program's place, against the float32 reference: the control
+    # whose chip readings set the limits
     cell = harness.find_cell(harness.load_benchmark(), name)
     cfg = dict(cell.config, num_nodes=2048, num_edges=14104)
     g = graphs.make_graph(cell.traffic["law"], cfg["num_nodes"],
                           cfg["num_edges"], cfg["num_features"],
                           cfg["num_classes"], graphs.rng_for(seed, "graph"))
-    ref = reference.train(cfg, g, seed)
-    control = reference.train(cfg, g, seed, precision="high")
+    ref = cell.model.train(cfg, g, seed, steps=CHECKED_STEPS)
+    control = cell.model.train(cfg, g, seed, steps=CHECKED_STEPS,
+                               precision="high")
     ok, checks = compare.judge(compare.train_numbers(control, ref),
                                cell.limits)
     assert not ok, checks

@@ -1,7 +1,7 @@
 """The readers of what the program records in repro.obs over a run, set-up
 included: nothing found gives None, hand-built spans and counters give the
-right numbers, and on a tiny cell the grid share the launches count agrees
-with the one the benchmark computes from the plan."""
+right numbers, and on a tiny cell the grid steps the launches count agree
+with the flat grid of the plan the step ran with."""
 import sys
 import time
 from pathlib import Path
@@ -11,7 +11,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from benchmarks.chip import harness, program_obs, readers, tiny  # noqa: E402
+from benchmarks.chip import harness, program_obs, tiny  # noqa: E402
 
 METRICS = ("setup_plan_s.train", "setup_compile_s.train",
            "kernel_grid_useful_share.train")
@@ -108,8 +108,23 @@ def test_metric_files_read_the_program_registry_and_ring():
     obs.reset()
 
 
-def test_grid_share_counted_by_the_launches_matches_the_plan(tmp_path):
+def _grid_by_op(kind: str) -> dict:
     from repro import obs
+    out: dict = {}
+    for labels, cell in obs.get_registry().get(
+            "kernel.grid_steps").series_items():
+        if labels["kind"] == kind:
+            out[labels["op"]] = out.get(labels["op"], 0.0) + cell[0]
+    return out
+
+
+def test_grid_share_counted_by_the_launches_matches_the_plan(tmp_path):
+    # every executed step adds, for each launch of the gather and fused
+    # kernels, the flat grid's steps for the plan the step ran with as
+    # walked, and the plan's chunks as owned
+    from repro import obs
+    from repro.kernels.segment_reduce import flat_grid_steps
+    from benchmarks.chip.drivers.train import CHECKED_STEPS
     obs.enable()
     obs.reset()
     name = "gcn-arxiv.train-powerlaw"
@@ -118,9 +133,20 @@ def test_grid_share_counted_by_the_launches_matches_the_plan(tmp_path):
         out = harness.load_driver(cell.traffic).run(
             cell, seed=2**33 + 5, seconds=0.1, tracer=harness.Tracer(False),
             t0=time.perf_counter())
-    from_plan = readers.plan_useful_share({"plans": out.layer["plans"]})
-    counted = program_obs.grid_useful_share()
-    assert from_plan < 100.0
-    assert counted == pytest.approx(from_plan, rel=1e-12)
+    plan = out.layer["plan"]
+    flat = flat_grid_steps(plan["input_chunks"], plan["out_blocks"],
+                           plan["max_chunks"])
+    assert flat < plan["out_blocks"] * plan["max_chunks"]
+    executed = CHECKED_STEPS + out.attempted
+    [(launches, _)] = out.layer["launch_groups"]
+    walked, owned = _grid_by_op("walked"), _grid_by_op("owned")
+    for kernel in ("gather_segment_reduce", "fused_transform_reduce"):
+        count = sum(launch.kernel == kernel for launch in launches)
+        ops = [op for op in walked if op.startswith(kernel)]
+        assert count >= 1 and ops
+        got = sum(walked[op] for op in ops)
+        assert got == executed * count * flat
+        assert sum(owned[op] for op in ops) == \
+            executed * count * plan["chunks"] <= got
     assert program_obs.compile_seconds("train.compile") > 0
     assert program_obs.span_seconds("plan.build") > 0

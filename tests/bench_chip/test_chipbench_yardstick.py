@@ -1,6 +1,6 @@
 """The on-chip benchmark's yardstick, checked on the CPU: trace reduction,
-operations and bytes, the peaks table, the plan's useful share and the
-generators."""
+operations and bytes, the launch recorder, the peaks table, the readers
+and the generators."""
 import sys
 from pathlib import Path
 
@@ -136,6 +136,34 @@ def test_fused_transform_reduce_cost_by_hand():
     assert c.least_s(v5e) == pytest.approx(112 / 819e9)
 
 
+def test_segment_softmax_cost_by_hand():
+    # 6 logits of 3 heads into 2 segments, fp32: per element a max, a
+    # subtraction, an exponential, a sum and a division
+    launch = costs.Launch("segment_softmax", num_rows=6, num_edges=6,
+                          num_segments=2, d_in=3, d_out=3, weighted=False,
+                          io_bytes=4)
+    c = costs.kernel_cost(launch)
+    assert c.flops == 5 * 6 * 3
+    # logits 6·3·4 read + ids 6·4 read + weights 6·3·4 written
+    assert c.bytes == 72 + 24 + 72
+
+
+def test_the_recorder_notes_a_softmax_launch():
+    import jax
+    import jax.numpy as jnp
+    from repro.core import ops
+    logits = jnp.arange(12, dtype=jnp.float32).reshape(6, 2) / 7
+    idx = jnp.array([0, 0, 1, 1, 1, 3], jnp.int32)
+    recorder = costs.LaunchRecorder()
+    with recorder.installed():
+        for x in (logits, logits[:, 0]):
+            jax.jit(lambda x: ops.segment_softmax(x, idx, 4,
+                                                  impl="pallas"))(x)
+    assert recorder.take() == [
+        costs.Launch("segment_softmax", 6, 6, 4, 2, 2, False, 4),
+        costs.Launch("segment_softmax", 6, 6, 4, 1, 1, False, 4)]
+
+
 def test_cost_of_an_unknown_kernel_is_an_error():
     with pytest.raises(KeyError):
         costs.kernel_cost(costs.Launch("sddmm", 1, 1, 1, 1, 1, False, 4))
@@ -158,22 +186,6 @@ def test_peaks_table_refuses_an_unknown_device():
     assert peaks.peaks_for("TPU v5 lite").flops_per_s == 197e12
     with pytest.raises(KeyError, match="no published peaks"):
         peaks.peaks_for("cpu")
-
-
-def test_plan_grid_useful_share_on_a_known_plan():
-    from repro.core.config_space import KernelConfig
-    from repro.core.plan import make_plan
-    # 8 segments of 64 rows each over 512 rows; s_b = 4 → 2 output blocks
-    # of 256 rows, each owning 2 chunks of m_b = 128; pinned to the worst
-    # case every block walks all 4 chunks
-    idx = np.repeat(np.arange(8), 64).astype(np.int32)
-    cfg = KernelConfig("PR", 4, 128, 128, 1)
-    plan = make_plan(idx, 8, config=cfg).pin_worst_case()
-    counts = np.asarray(plan.chunk_count)
-    assert counts.tolist() == [2, 2] and plan.max_chunks == 4
-    plans = [(int(counts.sum()), int(counts.size), int(plan.max_chunks))]
-    assert readers.plan_useful_share({"plans": plans}) == pytest.approx(50.0)
-    assert readers.plan_useful_share({"plans": []}) is None
 
 
 def test_readers_find_nothing_without_their_inputs():
